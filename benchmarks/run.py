@@ -46,6 +46,8 @@ def main() -> None:
     if args.only and not mods:
         print(f"# no module matches --only={args.only}", file=sys.stderr)
         sys.exit(2)
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failed = []
     for mod_name in mods:

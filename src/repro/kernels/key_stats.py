@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .compat import CompilerParams
 from .routing_lookup import require_int32
 
 
@@ -80,7 +79,7 @@ def _key_stats(keys: jax.Array, costs: jax.Array, num_keys: int,
             jax.ShapeDtypeStruct((1, padded_k), jnp.float32),
             jax.ShapeDtypeStruct((1, padded_k), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(keys_p, costs_p)

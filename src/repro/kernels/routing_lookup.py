@@ -23,8 +23,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def require_int32(kernel: str, name: str, arr) -> None:
     """Int32 contract check, outside the jit boundary.
@@ -95,7 +93,7 @@ def _routing_lookup(keys: jax.Array, table_keys: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, keys_p.shape[1]), jnp.int32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(keys_p, tkeys_p, tdests_p)

@@ -55,12 +55,6 @@ def collective_bytes(hlo_text: str) -> dict:
     return out
 
 
-
-def _cost_dict(compiled):
-    """compiled.cost_analysis() compat: jax < 0.5 returns [dict], newer dict."""
-    cost = compiled.cost_analysis()
-    return cost[0] if isinstance(cost, list) else cost
-
 def _probe_costs(cfg, shape, mesh, fsdp: bool, remat: bool):
     """XLA's cost_analysis counts a while-loop body ONCE, so scan-over-layers
     (and microbatch) totals are undercounted. Probe with 1-group and 2-group
@@ -153,7 +147,7 @@ def _lower_raw_inner(cfg, shape, mesh, fsdp, remat, microbatches):
                              donate_argnums=(1,))
             lowered = jitted.lower(params_abs, cache_abs, b_specs)
     compiled = lowered.compile()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
             "collective_bytes": collective_bytes(compiled.as_text())}
@@ -240,7 +234,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = collective_bytes(hlo)
 

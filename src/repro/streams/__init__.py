@@ -11,7 +11,7 @@ from .engine import STATE_BACKENDS, SUBSTRATES, IntervalReport, KeyedStage
 from .faults import (ChaosRunner, DropDelivery, DuplicateDelivery, FaultPlan,
                      FaultInjector, KillTask, RecoveryEvent, StallTask,
                      TaskKilled, TaskStalled)
-from .generator import WorkloadGen, zipf_frequencies
+from .generator import WorkloadGen, hot_set_drift_trace, zipf_frequencies
 from .operators import (BatchResult, Filter, IntervalBatchResult, MergeCounts,
                         Operator, PartialWordCount, WindowedSelfJoin,
                         WordCount)
@@ -22,7 +22,8 @@ from .topology import (StageSpec, Topology, TopologyReport, keyed_stage,
 
 __all__ = [
     "STATE_BACKENDS", "SUBSTRATES", "IntervalReport", "KeyedStage",
-    "WorkloadGen", "zipf_frequencies", "BatchResult", "Filter",
+    "WorkloadGen", "hot_set_drift_trace", "zipf_frequencies", "BatchResult",
+    "Filter",
     "IntervalBatchResult", "MergeCounts", "Operator", "PartialWordCount",
     "WindowedSelfJoin", "WordCount", "ColumnarSpec", "ColumnarStateStore",
     "KeyState", "TaskStateStore", "StageSpec", "Topology", "TopologyReport",

@@ -99,8 +99,8 @@ def get_backend(name: str) -> Type["StateBackend"]:
     return BACKENDS[name]
 
 
-def resolve_backend(name: str, operator, controller,
-                    vectorized: bool) -> Type["StateBackend"]:
+def resolve_backend(name: str, operator, controller, vectorized: bool,
+                    substrate: str = "numpy") -> Type["StateBackend"]:
     """Map a ``state_backend=`` request to a backend class.
 
     Explicit names validate via the class's :meth:`StateBackend.check`
@@ -111,7 +111,7 @@ def resolve_backend(name: str, operator, controller,
     stage vectorized; object otherwise)."""
     if name != "auto":
         cls = get_backend(name)
-        cls.check(operator, controller, vectorized)
+        cls.check(operator, controller, vectorized, substrate)
         return cls
     for cand in ("device", "columnar"):
         cls = get_backend(cand)
@@ -140,7 +140,8 @@ class StateBackend:
 
     # -- selection hooks -------------------------------------------------------
     @classmethod
-    def check(cls, operator, controller, vectorized: bool) -> None:
+    def check(cls, operator, controller, vectorized: bool,
+              substrate: str = "numpy") -> None:
         """Raise ``ValueError`` when an explicit request is unsupported."""
 
     @classmethod
@@ -465,7 +466,7 @@ class ColumnarBackend(HostStoreBackend):
     name = "columnar"
 
     @classmethod
-    def check(cls, operator, controller, vectorized):
+    def check(cls, operator, controller, vectorized, substrate="numpy"):
         if getattr(operator, "columnar_spec", None) is None:
             raise ValueError(
                 f"state_backend='columnar' requires an operator with a "
@@ -538,7 +539,7 @@ class DeviceBackend(StateBackend):
         return DeviceStateFleet(stage.window, stage.operator.columnar_spec)
 
     @classmethod
-    def check(cls, operator, controller, vectorized):
+    def check(cls, operator, controller, vectorized, substrate="numpy"):
         if not vectorized:
             raise ValueError(f"state_backend={cls.name!r} requires "
                              "vectorized=True (the per-tuple reference path "

@@ -168,6 +168,29 @@ def _evict_step(vals, pres, keep_cols):
     return vals, pres, pres.sum(axis=0), vals.sum(axis=0)
 
 
+@functools.partial(jax.jit, donate_argnums=_DONATE)
+def _set_cols(vals, pres, cols, new_vals, new_pres):
+    """Overwrite whole key columns of the ring (checkpoint, restore and
+    ``scale_to`` moves). ``cols`` is padded to a power-of-two bucket with
+    the padding-sink column and zero values, so the sink stays zero and a
+    handful of compiled shapes serve every batch size."""
+    return vals.at[:, cols].set(new_vals), pres.at[:, cols].set(new_pres)
+
+
+def _pad_cols(cols: np.ndarray, sink: int, vals_cols: np.ndarray,
+              pres_cols: np.ndarray):
+    """Pad ``cols`` and the (W1, n) column blocks to a power-of-two bucket."""
+    n = int(cols.shape[0])
+    cap = max(256, 1 << max(0, n - 1).bit_length())
+    idx = np.full(cap, sink, dtype=np.int32)
+    idx[:n] = cols
+    v = np.zeros((vals_cols.shape[0], cap), dtype=np.int32)
+    p = np.zeros_like(v)
+    v[:, :n] = vals_cols
+    p[:, :n] = pres_cols
+    return idx, v, p
+
+
 @functools.partial(jax.jit, static_argnames=("n_dest", "seed"))
 def _route_dense(all_keys, tkeys, tdests, *, n_dest: int, seed: int):
     """F(k) for EVERY key id at once: fmix32 hash + table-override scatter.
@@ -328,25 +351,29 @@ class DeviceStateFleet:
         vals = host_vals[:, rows].T.astype(np.float64)
         return self.spec.slot_bytes * pres + self.spec.bytes_per_unit * vals
 
+    def _cols(self, rows: np.ndarray) -> Tuple[np.ndarray, int]:
+        """Ring columns of key ids, and the padding-sink column."""
+        return rows, self.domain
+
+    def _write_cols(self, rows: np.ndarray, vals_cols: np.ndarray,
+                    pres_cols: np.ndarray) -> None:
+        cols, sink = self._cols(rows)
+        idx, v, p = _pad_cols(cols, sink, vals_cols, pres_cols)
+        self.vals, self.pres = _set_cols(self.vals, self.pres, idx, v, p)
+        self._host_dirty = True
+
     def clear_rows(self, rows: np.ndarray) -> None:
-        idx = jnp.asarray(rows.astype(np.int32))
-        self.vals = self.vals.at[:, idx].set(0)
-        self.pres = self.pres.at[:, idx].set(0)
+        zeros = np.zeros((self._ncols, rows.shape[0]), np.int32)
+        self._write_cols(rows, zeros, zeros)
         self.task[rows] = -1
         self.mem[rows] = 0.0
-        self._host_dirty = True
 
     def install_rows(self, rows: np.ndarray, vals_cols: np.ndarray,
                      pres_cols: np.ndarray, task_idx: int,
                      sizes_rows: np.ndarray) -> None:
-        idx = jnp.asarray(rows.astype(np.int32))
-        self.vals = self.vals.at[:, idx].set(
-            jnp.asarray(vals_cols.T.astype(np.int32)))
-        self.pres = self.pres.at[:, idx].set(
-            jnp.asarray(pres_cols.T.astype(np.int32)))
+        self._write_cols(rows, vals_cols.T, pres_cols.T)
         self.task[rows] = task_idx
         self.mem[rows] = sizes_rows.sum(axis=1)
-        self._host_dirty = True
 
 
 class _DeviceKeysView:

@@ -92,6 +92,8 @@ class KeyedStage:
         selects the per-tuple reference loop — same results, ~10x slower;
         kept for parity testing and as executable documentation.
       substrate: ``"numpy"`` or ``"pallas"`` — see the module docstring.
+        ``state_backend="sharded"`` rejects ``"pallas"`` (its route does
+        not run the kernel).
       state_backend: which :class:`~repro.streams.backends.StateBackend`
         holds the keyed state. ``"auto"`` (default) resolves device >
         columnar > object: the columnar store when the operator declares a
@@ -181,7 +183,7 @@ class KeyedStage:
         self._kernel_interpret = kernel_interpret
         # backend selection (and its support errors) precedes substrate init
         backend_cls = resolve_backend(state_backend, operator, controller,
-                                      vectorized)
+                                      vectorized, substrate)
         if substrate == "pallas":
             self._init_pallas(kernel_interpret)
         self.backend = backend_cls(self)

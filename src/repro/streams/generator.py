@@ -10,7 +10,7 @@ reaches ``|L_i(d) - L_{i-1}(d)| / L_{i-1}(d) >= f`` (the paper's rule).
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterator, Optional
+from typing import Iterator, List, Optional
 
 import numpy as np
 
@@ -99,3 +99,32 @@ class WorkloadGen:
         """Sample n concrete tuple keys from the current distribution."""
         p = self.freq / self.freq.sum()
         return self.rng.choice(self.keys, size=n, p=p)
+
+
+def hot_set_drift_trace(domain: int, z: float, tuples: int, intervals: int,
+                        *, hot: int, shift_every: int,
+                        seed: int = 0) -> List[np.ndarray]:
+    """Open-loop keyed traffic: per-interval int64 key arrays.
+
+    Keys are Zipf(``z``) over ``[0, domain)`` by rank, with ranks mapped to
+    key ids by a seeded permutation. Every ``shift_every`` intervals the
+    ``hot`` most popular ranks trade ids with ranks drawn from the tail, so
+    the hot set moves and a balancer has to follow it. Unlike
+    :meth:`WorkloadGen.interval`, nothing here reads a routing table: the
+    traffic is a function of the seed alone, so it cannot adapt to the
+    system it is fed to.
+    """
+    if not 0 < hot < domain:
+        raise ValueError(f"hot must be in (0, {domain}), got {hot}")
+    rng = np.random.default_rng(seed)
+    cdf = np.cumsum(np.arange(1, domain + 1, dtype=np.float64) ** -z)
+    cdf /= cdf[-1]
+    ids = rng.permutation(domain).astype(np.int64)       # rank -> key id
+    trace = []
+    for i in range(intervals):
+        if i and i % shift_every == 0:
+            tail = hot + rng.choice(domain - hot, size=hot, replace=False)
+            ids[:hot], ids[tail] = ids[tail], ids[:hot].copy()
+        ranks = np.searchsorted(cdf, rng.random(tuples), side="right")
+        trace.append(ids[np.minimum(ranks, domain - 1)])
+    return trace

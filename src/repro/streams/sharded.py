@@ -57,10 +57,13 @@ DeviceBackend`'s host logic cannot tell the difference, which is what makes
 structural property rather than a numerical accident.
 
 CPU CI runs this with ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-(virtual devices; architecture demonstration). Real speedups, compiled
-Mosaic kernels inside the shard_map, and donation of the sharded state are
-TPU follow-ups — the route uses the jnp twin of the routing kernel
-unconditionally for now.
+(virtual devices; architecture demonstration); on a TPU host every chip is
+one shard. The route is the jnp twin of the routing kernel — the Pallas
+kernel does not run inside the shard_map, so ``substrate="pallas"`` is
+rejected by :meth:`ShardedDeviceBackend.check` rather than silently
+ignored. Every input is placed on the mesh with a ``NamedSharding``
+(tuple chunks split over ``"shard"``, step and table operands
+replicated), so nothing is staged on one device and resharded.
 """
 
 from __future__ import annotations
@@ -70,7 +73,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.kernels.routing_lookup import _fmix32
@@ -115,7 +117,7 @@ def _build_step_add(mesh, S: int, B: int):
         return (vals, pres, counts, win0, slot0,
                 pres.sum(axis=0), vals.sum(axis=0))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, "shard"), P(None, "shard"), P("shard", None),
                   P(None), P(None)),
@@ -157,7 +159,7 @@ def _build_step_max(mesh, S: int, B: int):
         return (vals, pres, counts, win0, slot0,
                 pres.sum(axis=0), vals.sum(axis=0))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, "shard"), P(None, "shard"), P("shard", None),
                   P("shard", None), P(None), P(None)),
@@ -183,8 +185,9 @@ def _build_route(mesh, S: int, B: int, n_dest: int, seed: int):
         # no-op (same trick as device._route_dense's padding row)
         return base.at[slot].set(jnp.where(ok, td, base[B]))
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(P(None), P(None)),
-                             out_specs=P("shard")))
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(P(None), P(None)),
+                                 out_specs=P("shard")))
 
 
 class ShardedStateFleet(DeviceStateFleet):
@@ -211,6 +214,8 @@ class ShardedStateFleet(DeviceStateFleet):
         self.n_shards = int(n_shards)
         self.mesh = make_mesh((self.n_shards,), ("shard",))
         self._sharding = NamedSharding(self.mesh, P(None, "shard"))
+        self._chunks = NamedSharding(self.mesh, P("shard", None))
+        self._replicated = NamedSharding(self.mesh, P())
         self._block = 0            # B: keys per shard; local sink row is B
         self._chunk_cap = 0        # per-shard tuple-chunk pad bucket (pow2 HWM)
         self._step_fns = {}        # (mode, B) -> jitted shard_map
@@ -281,7 +286,7 @@ class ShardedStateFleet(DeviceStateFleet):
         flat = np.full(self.n_shards * cap, pad, dtype=np.int32)
         if n:
             flat[:n] = arr
-        return jnp.asarray(flat.reshape(self.n_shards, cap))
+        return jax.device_put(flat.reshape(self.n_shards, cap), self._chunks)
 
     def interval_step(self, keys: np.ndarray, tuple_vals: Optional[np.ndarray],
                       dest_dense, n_tasks: int, keep_cols: np.ndarray,
@@ -303,8 +308,8 @@ class ShardedStateFleet(DeviceStateFleet):
             build = _build_step_add if mode == "add" else _build_step_max
             fn = build(self.mesh, S, self._block)
             self._step_fns[fn_key] = fn
-        cur = jnp.asarray(cur_col)
-        keep = jnp.asarray(keep_cols)
+        cur = jax.device_put(cur_col, self._replicated)
+        keep = jax.device_put(keep_cols, self._replicated)
         if mode == "add":
             out = fn(self.vals, self.pres, kchunk, cur, keep)
         else:
@@ -324,8 +329,8 @@ class ShardedStateFleet(DeviceStateFleet):
                     seed: int, use_kernel: bool, interpret: Optional[bool]):
         """S-way parallel dense route refresh from the replicated table.
 
-        ``use_kernel`` is accepted for interface parity but the jnp twin is
-        used unconditionally: Pallas-inside-shard_map is the TPU follow-up.
+        Always the jnp twin: ``use_kernel`` is never True here, because
+        :meth:`ShardedDeviceBackend.check` rejects ``substrate="pallas"``.
         """
         fn_key = (self._block, int(n_dest), int(seed))
         fn = self._route_fns.get(fn_key)
@@ -333,8 +338,8 @@ class ShardedStateFleet(DeviceStateFleet):
             fn = _build_route(self.mesh, self.n_shards, self._block,
                               int(n_dest), int(seed))
             self._route_fns[fn_key] = fn
-        return fn(jnp.asarray(tkeys.astype(np.int32)),
-                  jnp.asarray(tdests.astype(np.int32)))
+        return fn(jax.device_put(tkeys.astype(np.int32), self._replicated),
+                  jax.device_put(tdests.astype(np.int32), self._replicated))
 
     def dest_host_dense(self, dev) -> np.ndarray:
         return self._to_dense_1d(dev).astype(np.int64)
@@ -347,25 +352,9 @@ class ShardedStateFleet(DeviceStateFleet):
             self._host_dirty = False
         return self._host_vals, self._host_pres
 
-    def clear_rows(self, rows: np.ndarray) -> None:
-        idx = jnp.asarray(self._gcols(rows).astype(np.int32))
-        self.vals = self.vals.at[:, idx].set(0)
-        self.pres = self.pres.at[:, idx].set(0)
-        self.task[rows] = -1
-        self.mem[rows] = 0.0
-        self._host_dirty = True
-
-    def install_rows(self, rows: np.ndarray, vals_cols: np.ndarray,
-                     pres_cols: np.ndarray, task_idx: int,
-                     sizes_rows: np.ndarray) -> None:
-        idx = jnp.asarray(self._gcols(rows).astype(np.int32))
-        self.vals = self.vals.at[:, idx].set(
-            jnp.asarray(vals_cols.T.astype(np.int32)))
-        self.pres = self.pres.at[:, idx].set(
-            jnp.asarray(pres_cols.T.astype(np.int32)))
-        self.task[rows] = task_idx
-        self.mem[rows] = sizes_rows.sum(axis=1)
-        self._host_dirty = True
+    def _cols(self, rows: np.ndarray) -> Tuple[np.ndarray, int]:
+        """Interleaved global columns; shard 0's local sink is column B."""
+        return self._gcols(rows), self._block
 
 
 @register_backend
@@ -387,6 +376,16 @@ class ShardedDeviceBackend(DeviceBackend):
         stage = self.stage
         return ShardedStateFleet(stage.window, stage.operator.columnar_spec,
                                  n_shards=stage.n_shards)
+
+    @classmethod
+    def check(cls, operator, controller, vectorized, substrate="numpy"):
+        super().check(operator, controller, vectorized, substrate)
+        if substrate == "pallas":
+            raise ValueError(
+                "state_backend='sharded' does not run the Pallas routing "
+                "kernel inside its shard_map (the per-shard route is the jnp "
+                "twin); use substrate='numpy', or state_backend='device' for "
+                "the kernel route on one device")
 
     @classmethod
     def auto_eligible(cls, operator, controller, vectorized):

@@ -23,8 +23,7 @@ SCRIPT = textwrap.dedent("""
     from repro.sharding import rules, ctx as shard_ctx
     from repro.train.optimizer import OptConfig
     from repro.train.train_step import make_train_step, make_serve_step
-    from repro.launch.dryrun import (_cost_dict, abstract_opt_state,
-                                     collective_bytes)
+    from repro.launch.dryrun import abstract_opt_state, collective_bytes
 
     mesh = jax.make_mesh((2, 4), ("data", "model"))
     cfg = smoke_config("granite_moe_3b_a800m")
@@ -44,7 +43,7 @@ SCRIPT = textwrap.dedent("""
                      donate_argnums=(0, 1))
         lowered = jt.lower(pa, oa, b, pl)
     compiled = lowered.compile()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     mem = compiled.memory_analysis()
     print(json.dumps({
@@ -66,7 +65,7 @@ SCRIPT = textwrap.dedent("""
         low2 = js.lower(pa, ca, {"tokens": jax.ShapeDtypeStruct((8, 1), jnp.int32)}, pl)
     comp2 = low2.compile()
     print(json.dumps(
-        {"decode_flops": float(_cost_dict(comp2).get("flops", 0))}))
+        {"decode_flops": float(comp2.cost_analysis().get("flops", 0))}))
 """)
 
 

@@ -194,12 +194,14 @@ def test_shard_count_is_observationally_invisible():
 
 
 def test_sharded_with_pallas_substrate_matches_object():
-    """substrate='pallas' routes the host paths through the kernel; the
-    sharded route refresh stays on the jnp twin (accepted + documented),
-    and parity must still be exact."""
+    """The Pallas route does not run inside the sharded step, so the stage
+    refuses substrate='pallas' instead of silently routing through the jnp
+    twin; the same stream on the default substrate matches the oracle."""
+    with pytest.raises(ValueError, match="substrate='numpy'"):
+        make_stage(WordCount(), "sharded", substrate="pallas")
     gens = [WorkloadGen(k=300, z=1.0, f=0.5, seed=5, window=3)
             for _ in range(2)]
-    stages = [make_stage(WordCount(), "sharded", substrate="pallas"),
+    stages = [make_stage(WordCount(), "sharded"),
               make_stage(WordCount(), "object")]
     for i in range(4):
         for gen, stage in zip(gens, stages):
@@ -208,6 +210,47 @@ def test_sharded_with_pallas_substrate_matches_object():
             drawn = gen.draw_tuples(500).astype(np.int64)
             stage.process_interval_arrays(drawn, np.full(500, i))
     assert_stages_identical(*stages)
+
+
+def test_sharded_check_rejects_pallas_substrate():
+    """The refusal lives in the backend's own check, before any mesh or
+    fleet is built; the single-device backend still takes the kernel."""
+    from repro.streams.backends import get_backend
+    ctrl = _hash32_controller()
+    with pytest.raises(ValueError, match="shard_map"):
+        get_backend("sharded").check(WordCount(), ctrl, True, "pallas")
+    get_backend("sharded").check(WordCount(), ctrl, True, "numpy")
+    get_backend("device").check(WordCount(), ctrl, True, "pallas")
+
+
+def test_sharded_checkpoint_clears_rows_on_make_mesh_mesh():
+    """Regression: checkpointing extracts every held key (``clear_rows``
+    scatters into the mesh-sharded ring) and reinstalls it. On a mesh with
+    Explicit axes that scatter raised ShardingTypeError; make_mesh builds
+    Auto axes, and the round trip must leave the ring bit-identical."""
+    from jax.sharding import AxisType
+
+    from repro.streams import checkpoint_stage
+
+    stage = make_stage(WordCount(), "sharded", window=2)
+    fleet = stage.backend._fleet
+    assert set(fleet.mesh.axis_types) == {AxisType.Auto}
+    rng = np.random.default_rng(8)
+    stage.process_interval_arrays(rng.integers(0, 700, 900).astype(np.int64))
+    before = [np.asarray(fleet.vals).copy(), np.asarray(fleet.pres).copy()]
+    held = stage.total_state_keys()
+    checkpoint_stage(stage)
+    np.testing.assert_array_equal(np.asarray(fleet.vals), before[0])
+    np.testing.assert_array_equal(np.asarray(fleet.pres), before[1])
+    assert stage.total_state_keys() == held
+    assert fleet.vals.sharding.spec == jax.sharding.PartitionSpec(None,
+                                                                  "shard")
+    # and a direct clear drops exactly that key's column
+    row = int(np.nonzero(fleet.task[:fleet.domain] >= 0)[0][0])
+    fleet.clear_rows(np.array([row]))
+    assert fleet.task[row] == -1
+    assert not fleet.host_state()[1][:, row].any()
+    assert stage.total_state_keys() == held - 1
 
 
 # -- compile-once: the sharded step must not retrace across intervals --------

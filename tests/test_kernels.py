@@ -108,7 +108,7 @@ _EMPTY_D = jnp.zeros((8,), jnp.int32)
 
 @pytest.mark.parametrize("bad", [jnp.int64, jnp.float32, jnp.uint32])
 def test_routing_lookup_rejects_non_int32_keys(bad):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         keys = jnp.asarray([1, 2, 3]).astype(bad)
         with pytest.raises(TypeError, match="int32 keys"):
             routing_lookup(keys, _EMPTY_K, _EMPTY_D, 4, interpret=True)
@@ -126,7 +126,7 @@ def test_routing_lookup_rejects_non_int32_table():
 
 @pytest.mark.parametrize("bad", [jnp.int64, jnp.float32, jnp.int16])
 def test_key_stats_rejects_non_int32_keys(bad):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         keys = jnp.asarray([0, 1, 2]).astype(bad)
         with pytest.raises(TypeError, match="int32 keys"):
             key_stats(keys, jnp.ones((3,), jnp.float32), 4, interpret=True)
