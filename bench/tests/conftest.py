@@ -1,0 +1,11 @@
+"""The benchmark's own tests run on the CPU at tiny sizes, with the Pallas
+kernels in interpret mode: they check the harness, never a timing."""
+
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1]
+ROOT = BENCH.parent
+for p in (str(BENCH), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)

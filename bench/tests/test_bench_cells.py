@@ -1,0 +1,34 @@
+"""Every cell end to end at a tiny size on the CPU: the harness drives the
+program, the reference agrees, and the control does not."""
+
+import pytest
+
+import tiny
+
+
+@pytest.mark.parametrize("cell", tiny.CELLS)
+def test_cell_is_correct_and_its_control_is_not(cell):
+    result = tiny.run(cell, control=True)
+    checks = result["checks"]
+    assert result["correct"], checks
+    assert result["failed"] == 0 and result["attempted"] > 0
+    assert all(c["value"] == 0 for c in checks.values())
+    # the result line's keys the benchmark's contract fixes, checks last
+    assert list(result)[:5] == ["correct", "attempted", "failed", "metrics",
+                                "device"]
+    assert list(result)[-1] == "checks"
+    assert "setup_s" in result["metrics"]
+    assert len(result["metrics"]) >= 2
+    # the control (int16 window) fails at least one number
+    assert any(v > 0 for v in result["control"].values()), result["control"]
+
+
+def test_traced_run_reports_host_metrics():
+    """On the CPU the trace has no TPU plane: device metrics are left out,
+    the host-clock and counter metrics are there."""
+    result = tiny.run("wordcount.drift.sat", trace=True)
+    assert result["correct"]
+    assert "interval_ms.sat" in result["metrics"]
+    assert "plan_ms.sat" in result["metrics"]
+    assert "device_idle_pct.sat" not in result["metrics"]
+
