@@ -25,6 +25,7 @@ import numpy as np
 
 from .balancer import (Assignment, BalanceConfig, KeyStats, RebalanceResult,
                        metrics, resolve_strategy)
+from .telemetry import count, span
 
 
 @dataclasses.dataclass
@@ -208,12 +209,16 @@ class RebalanceController:
             ev = ControllerEvent(self._interval, False, th)
             self.history.append(ev)
             return ev
-        th = metrics.theta_for(stats, self.assignment)
-        if not force and th <= self.config.theta_max:
+        with span("trigger"):
+            th = metrics.theta_for(stats, self.assignment)
+            quiet = not force and th <= self.config.theta_max
+        if quiet:
             ev = ControllerEvent(self._interval, False, th)
             self.history.append(ev)
             return ev
-        result = self.strategy.plan(stats, self.assignment, self.config)
+        with span("plan"):
+            result = self.strategy.plan(stats, self.assignment, self.config)
+        count("plans")
         # Pause/migrate/Resume: the executor moves state for Delta(F,F') only;
         # in jitted substrates this is a step-boundary double-buffer swap.
         if self.executor is not None and len(result.moved_keys):

@@ -53,6 +53,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Type
 import numpy as np
 
 from repro.core.balancer import Assignment, KeyStats, metrics
+from repro.core.telemetry import count, span
 
 from .state import ColumnarStateStore, TaskStateStore
 
@@ -367,16 +368,16 @@ class HostStoreBackend(StateBackend):
         two passes — weights = per-key cost, weights = per-key freq — yield
         c(k) and g(k). Accumulation is float32 on-device; reports therefore
         match the numpy path to ~1e-6 relative, not bit-for-bit."""
+        from .device import to_device, to_host   # lazy: jax is loaded here
         stage = self.stage
-        jnp = stage._jnp
         num = int(max(seen.max(initial=0), held_keys.max(initial=0))) + 1
-        seen_dev = jnp.asarray(seen.astype(np.int32))
-        _, cost_d = stage._kernel_stats(seen_dev, jnp.asarray(cost_parts),
+        seen_dev = to_device(seen.astype(np.int32))
+        _, cost_d = stage._kernel_stats(seen_dev, to_device(cost_parts),
                                         num, interpret=stage._kernel_interpret)
-        _, freq_d = stage._kernel_stats(seen_dev, jnp.asarray(freq_parts),
+        _, freq_d = stage._kernel_stats(seen_dev, to_device(freq_parts),
                                         num, interpret=stage._kernel_interpret)
-        cost = np.asarray(cost_d, dtype=np.float64)
-        freq = np.asarray(freq_d, dtype=np.float64)
+        cost = to_host(cost_d).astype(np.float64)
+        freq = to_host(freq_d).astype(np.float64)
         mem = metrics.segment_sum(held_sizes, held_keys, num)
         # universe = seen ∪ held — held membership, not mem > 0: a quiet key
         # whose window fully evicted still occupies the store and must stay
@@ -664,13 +665,15 @@ class DeviceBackend(StateBackend):
                      self._fleet.domain, stage.n_tasks)
         if self._dest_dense_cache is None \
                 or self._dest_dense_cache[0] != cache_key:
-            tk, td = assignment.table_arrays(stage._table_capacity)
-            dev = self._fleet.route_dense(
-                tk, td, assignment.n_dest, seed=self._device_seed,
-                use_kernel=(stage.substrate == "pallas"),
-                interpret=stage._kernel_interpret)
-            self._dest_dense_cache = (cache_key, dev,
-                                      self._fleet.dest_host_dense(dev))
+            with span("route", table=stage._table_capacity):
+                tk, td = assignment.table_arrays(stage._table_capacity)
+                dev = self._fleet.route_dense(
+                    tk, td, assignment.n_dest, seed=self._device_seed,
+                    use_kernel=(stage.substrate == "pallas"),
+                    interpret=stage._kernel_interpret)
+                self._dest_dense_cache = (cache_key, dev,
+                                          self._fleet.dest_host_dense(dev))
+            count("route_refreshes")
         return self._dest_dense_cache[1], self._dest_dense_cache[2]
 
     # -- one interval as ONE fused device step ---------------------------------
@@ -681,6 +684,7 @@ class DeviceBackend(StateBackend):
         for device operators (their closed forms are batch-boundary
         invariant), so only the ``buffered`` count needs the host split; the
         step itself sees the whole interval."""
+        from .device import to_host
         stage = self.stage
         iv = stage.begin_interval()
         n = int(keys.shape[0])
@@ -691,8 +695,9 @@ class DeviceBackend(StateBackend):
         buffered_count = 0
         pause_hi = stage.pause_window(n)
         if pause_hi is not None:
-            buffered_count = int(np.isin(keys[:pause_hi],
-                                         stage._pending_delta_arr).sum())
+            with span("pause"):
+                buffered_count = int(np.isin(keys[:pause_hi],
+                                             stage._pending_delta_arr).sum())
         stage.clear_pause()
 
         # ring-column bookkeeping (host mirror of the columnar _col_iv)
@@ -743,63 +748,62 @@ class DeviceBackend(StateBackend):
             step = fleet.interval_step(keys, tv, dest_dev, stage.n_tasks,
                                        keep, cur, op.device_mode)
             dom = fleet.domain
-            counts_h = np.asarray(step[0])[:dom]
-            win0_h = np.asarray(step[1])[:dom]
-            slot0_h = np.asarray(step[2])[:dom]
-            held_cnt = np.asarray(step[3])[:dom]
-            held_sum = np.asarray(step[4])[:dom]
+            counts_h, win0_h, slot0_h, held_cnt, held_sum = (
+                to_host(a)[:dom] for a in step[:5])
 
-            seen_mask = counts_h > 0
-            gk = np.nonzero(seen_mask)[0].astype(np.int64)
-            key_cost_g, out_vals, emit_sum = op.device_finish(
-                counts_h[seen_mask].astype(np.int64),
-                win0_h[seen_mask].astype(np.int64),
-                slot0_h[seen_mask].astype(np.int64))
-            if out_vals is not None:
-                stage.outputs.update(zip(gk.tolist(), out_vals.tolist()))
-            stage.emitted_sum += emit_sum
-            if op.device_unit_cost:
-                if step[5] is not None:           # max mode: device bincount
-                    task_cost = np.asarray(step[5]).astype(np.float64)
-                else:                             # add mode: counts are host
-                    task_cost = np.bincount(dest_host[:dom],
-                                            weights=counts_h,
+            with span("finish"):
+                seen_mask = counts_h > 0
+                gk = np.nonzero(seen_mask)[0].astype(np.int64)
+                key_cost_g, out_vals, emit_sum = op.device_finish(
+                    counts_h[seen_mask].astype(np.int64),
+                    win0_h[seen_mask].astype(np.int64),
+                    slot0_h[seen_mask].astype(np.int64))
+                if out_vals is not None:
+                    stage.outputs.update(zip(gk.tolist(), out_vals.tolist()))
+                stage.emitted_sum += emit_sum
+                if op.device_unit_cost:
+                    if step[5] is not None:       # max mode: device bincount
+                        task_cost = to_host(step[5]).astype(np.float64)
+                    else:                         # add mode: counts are host
+                        task_cost = np.bincount(dest_host[:dom],
+                                                weights=counts_h,
+                                                minlength=stage.n_tasks)
+                else:
+                    task_cost = np.bincount(dest_host[gk],
+                                            weights=key_cost_g,
                                             minlength=stage.n_tasks)
-            else:
-                task_cost = np.bincount(dest_host[gk], weights=key_cost_g,
-                                        minlength=stage.n_tasks)
 
-            # host mirrors: ownership labels (new keys adopt F(k); evicted
-            # keys clear) and the closed-form S(k, w) per key
-            alive = held_cnt > 0
-            t = fleet.task
-            t[:dom] = np.where(alive,
-                               np.where(t[:dom] >= 0, t[:dom],
-                                        dest_host[:dom].astype(np.int32)),
-                               -1)
-            fleet.mem[:dom] = (spec.slot_bytes * held_cnt
-                               + spec.bytes_per_unit * held_sum)
-            fleet.mem[:dom][~alive] = 0.0
+                # host mirrors: ownership labels (new keys adopt F(k);
+                # evicted keys clear) and the closed-form S(k, w) per key
+                alive = held_cnt > 0
+                t = fleet.task
+                t[:dom] = np.where(alive,
+                                   np.where(t[:dom] >= 0, t[:dom],
+                                            dest_host[:dom].astype(np.int32)),
+                                   -1)
+                fleet.mem[:dom] = (spec.slot_bytes * held_cnt
+                                   + spec.bytes_per_unit * held_sum)
+                fleet.mem[:dom][~alive] = 0.0
 
             # stat universe = seen ∪ held == alive: a seen key's current slot
             # never expires at its own boundary, so seen ⊆ held-after
-            uni = np.nonzero(alive)[0].astype(np.int64)
-            if uni.size:
-                cost = np.zeros(uni.size, dtype=np.float64)
-                cost[np.searchsorted(uni, gk)] = key_cost_g
-                if stage.controller.stats_mode == "sketch":
-                    # one fold with every channel: the fused step already
-                    # aggregated per key, so this is the same multiset the
-                    # host backends stream in (see HostStoreBackend)
-                    stage.controller.ingest(
-                        uni, cost, mem=fleet.mem[uni],
-                        freq=counts_h[alive].astype(np.float64))
-                    stats = SKETCH_PENDING
-                else:
-                    stats = KeyStats(keys=uni,
-                                     cost=cost,
-                                     mem=fleet.mem[uni].copy(),
-                                     freq=counts_h[alive].astype(np.float64))
+            with span("stats"):
+                uni = np.nonzero(alive)[0].astype(np.int64)
+                if uni.size:
+                    cost = np.zeros(uni.size, dtype=np.float64)
+                    cost[np.searchsorted(uni, gk)] = key_cost_g
+                    freq = counts_h[alive].astype(np.float64)
+                    if stage.controller.stats_mode == "sketch":
+                        # one fold with every channel: the fused step
+                        # already aggregated per key, so this is the same
+                        # multiset the host backends stream in (see
+                        # HostStoreBackend)
+                        stage.controller.ingest(uni, cost,
+                                                mem=fleet.mem[uni], freq=freq)
+                        stats = SKETCH_PENDING
+                    else:
+                        stats = KeyStats(keys=uni, cost=cost,
+                                         mem=fleet.mem[uni].copy(), freq=freq)
         else:
             if fleet.domain and expire.any():
                 held_cnt, held_sum = fleet.evict(keep)
@@ -836,12 +840,15 @@ class DeviceBackend(StateBackend):
         fleet = self._fleet
         if not fleet.domain:
             return None
-        uni = np.nonzero(fleet.task[:fleet.domain] >= 0)[0].astype(np.int64)
-        if not uni.size:
-            return None
-        if self.stage.controller.stats_mode == "sketch":
-            self.stage.controller.ingest(uni, np.zeros(uni.size),
-                                         mem=fleet.mem[uni])
-            return SKETCH_PENDING
-        return KeyStats(keys=uni, cost=np.zeros(uni.size),
-                        mem=fleet.mem[uni].copy(), freq=np.zeros(uni.size))
+        with span("stats"):
+            uni = np.nonzero(fleet.task[:fleet.domain] >= 0)[0] \
+                .astype(np.int64)
+            if not uni.size:
+                return None
+            if self.stage.controller.stats_mode == "sketch":
+                self.stage.controller.ingest(uni, np.zeros(uni.size),
+                                             mem=fleet.mem[uni])
+                return SKETCH_PENDING
+            return KeyStats(keys=uni, cost=np.zeros(uni.size),
+                            mem=fleet.mem[uni].copy(),
+                            freq=np.zeros(uni.size))

@@ -71,22 +71,43 @@ tests — rebalances never fall back to the object path.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.telemetry import count, span
 from repro.kernels.routing_lookup import _fmix32
 
 from .state import ColumnarPack, ColumnarSpec
 
 _INT32_MIN = np.iinfo(np.int32).min
 
-#: python-side-effect trace counters: the increments below run at TRACE time
-#: only, so tests can assert the fused step compiles once across intervals
-#: (same pattern as test_engine_substrate's retrace counting).
-TRACE_COUNTS = {"interval_step": 0, "evict_step": 0, "route_dense": 0}
+
+def to_host(x) -> np.ndarray:
+    """Host copy of a device array, as one ``pull`` span counted in
+    ``d2h_bytes`` (its time includes waiting for the step that makes
+    ``x``). A host array passes through untouched."""
+    if isinstance(x, np.ndarray):
+        return x
+    n = int(x.nbytes)
+    with span("pull", bytes=n):
+        out = np.asarray(x)
+    count("d2h_bytes", n)
+    return out
+
+
+def to_device(x, sharding=None) -> jax.Array:
+    """Upload a host array (onto ``sharding`` when given), counted in
+    ``h2d_bytes`` as placed: every device's shard, in the device dtype."""
+    out = jnp.asarray(x) if sharding is None else jax.device_put(x, sharding)
+    shard = math.prod(out.sharding.shard_shape(out.shape))
+    count("h2d_bytes",
+          shard * out.dtype.itemsize * len(out.sharding.device_set))
+    return out
+
 
 # XLA cannot alias donated buffers across calls on CPU and warns per call;
 # elsewhere donation lets the (window+1, domain+1) state update in place.
@@ -109,7 +130,7 @@ def _interval_step_add(vals, pres, counts, cur_col, keep_cols):
     held slot-count and value-sum AFTER eviction. In add mode the slot
     delta IS the count, so the whole update is elementwise.
     """
-    TRACE_COUNTS["interval_step"] += 1
+    count("retrace.device.interval_step")
     win0 = vals.sum(axis=0)
     slot0 = (vals * cur_col[:, None]).sum(axis=0)
     seen = (counts > 0).astype(jnp.int32)
@@ -139,7 +160,7 @@ def _interval_step_max(vals, pres, keys, tvals, dest_dense, cur_col,
     per-task tuple bincount. Unlike add mode the fold genuinely needs the
     raw tuple values, so the scatters stay on-device.
     """
-    TRACE_COUNTS["interval_step"] += 1
+    count("retrace.device.interval_step")
     d1 = vals.shape[1]
     pad_row = d1 - 1
     counts = jnp.zeros((d1,), jnp.int32).at[keys].add(jnp.int32(1))
@@ -162,7 +183,7 @@ def _interval_step_max(vals, pres, keys, tvals, dest_dense, cur_col,
 @functools.partial(jax.jit, donate_argnums=_DONATE)
 def _evict_step(vals, pres, keep_cols):
     """Boundary eviction for a tuple-free interval (no slot updates)."""
-    TRACE_COUNTS["evict_step"] += 1
+    count("retrace.device.evict_step")
     vals = vals * keep_cols[:, None]
     pres = pres * keep_cols[:, None]
     return vals, pres, pres.sum(axis=0), vals.sum(axis=0)
@@ -200,7 +221,7 @@ def _route_dense(all_keys, tkeys, tdests, *, n_dest: int, seed: int):
     bit-equal to the host planner's Hash32. Empty table slots (-1) scatter
     onto the padding row, whose dest is never read.
     """
-    TRACE_COUNTS["route_dense"] += 1
+    count("retrace.device.route_dense")
     h = _fmix32(all_keys.astype(jnp.uint32) ^ jnp.uint32(seed & 0xFFFFFFFF))
     base = (h % jnp.uint32(n_dest)).astype(jnp.int32)
     pad_row = all_keys.shape[0] - 1
@@ -276,39 +297,39 @@ class DeviceStateFleet:
         computed host-side — see the module docstring) and ``task_counts``
         is None there (derive it from counts + the host dest mirror).
         """
-        if mode == "add":
-            counts = np.bincount(keys, minlength=self.domain + 1) \
-                .astype(np.int32)
-            out = _interval_step_add(self.vals, self.pres,
-                                     jnp.asarray(counts),
-                                     jnp.asarray(cur_col),
-                                     jnp.asarray(keep_cols))
+        with span("step"):
+            if mode == "add":
+                counts = np.bincount(keys, minlength=self.domain + 1) \
+                    .astype(np.int32)
+                out = _interval_step_add(self.vals, self.pres,
+                                         to_device(counts), to_device(cur_col),
+                                         to_device(keep_cols))
+                self.vals, self.pres = out[0], out[1]
+                self._host_dirty = True
+                return (counts,) + tuple(out[2:]) + (None,)
+            n = int(keys.shape[0])
+            if n > self._keys_cap:
+                self._keys_cap = max(1024, 1 << (n - 1).bit_length())
+            cap = self._keys_cap
+            kp = np.empty(cap, dtype=np.int32)
+            kp[:n] = keys
+            kp[n:] = self.domain
+            tv = np.empty(cap, dtype=np.int32)
+            tv[:n] = tuple_vals
+            tv[n:] = _INT32_MIN
+            out = _interval_step_max(self.vals, self.pres, to_device(kp),
+                                     to_device(tv), dest_dense,
+                                     to_device(cur_col), to_device(keep_cols),
+                                     n_tasks=n_tasks)
             self.vals, self.pres = out[0], out[1]
             self._host_dirty = True
-            return (counts,) + tuple(out[2:]) + (None,)
-        n = int(keys.shape[0])
-        if n > self._keys_cap:
-            self._keys_cap = max(1024, 1 << (n - 1).bit_length())
-        cap = self._keys_cap
-        kp = np.empty(cap, dtype=np.int32)
-        kp[:n] = keys
-        kp[n:] = self.domain
-        tv = np.empty(cap, dtype=np.int32)
-        tv[:n] = tuple_vals
-        tv[n:] = _INT32_MIN
-        out = _interval_step_max(self.vals, self.pres, jnp.asarray(kp),
-                                 jnp.asarray(tv), dest_dense,
-                                 jnp.asarray(cur_col), jnp.asarray(keep_cols),
-                                 n_tasks=n_tasks)
-        self.vals, self.pres = out[0], out[1]
-        self._host_dirty = True
-        return out[2:]
+            return out[2:]
 
     def evict(self, keep_cols: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        out = _evict_step(self.vals, self.pres, jnp.asarray(keep_cols))
+        out = _evict_step(self.vals, self.pres, to_device(keep_cols))
         self.vals, self.pres = out[0], out[1]
         self._host_dirty = True
-        return np.asarray(out[2]), np.asarray(out[3])
+        return to_host(out[2]), to_host(out[3])
 
     def route_dense(self, tkeys: np.ndarray, tdests: np.ndarray, n_dest: int,
                     seed: int, use_kernel: bool,
@@ -317,8 +338,8 @@ class DeviceStateFleet:
         d1 = self.domain + 1
         if self._all_keys is None or int(self._all_keys.shape[0]) != d1:
             self._all_keys = jnp.arange(d1, dtype=jnp.int32)
-        tk = jnp.asarray(tkeys.astype(np.int32))
-        td = jnp.asarray(tdests.astype(np.int32))
+        tk = to_device(tkeys.astype(np.int32))
+        td = to_device(tdests.astype(np.int32))
         if use_kernel:
             from repro.kernels.routing_lookup import routing_lookup
             return routing_lookup(self._all_keys, tk, td, n_dest, seed=seed,
@@ -331,13 +352,13 @@ class DeviceStateFleet:
         Returns ``(domain+1,)`` int64 with ``out[k] == F(k)``. The single-
         device layout already is key-aligned; sharded fleets override this to
         de-interleave their per-shard blocks."""
-        return np.asarray(dev).astype(np.int64)
+        return to_host(dev).astype(np.int64)
 
     # -- host snapshots (pack contract + introspection) -------------------------
     def host_state(self) -> Tuple[np.ndarray, np.ndarray]:
         if self._host_dirty:
-            self._host_vals = np.asarray(self.vals)
-            self._host_pres = np.asarray(self.pres)
+            self._host_vals = to_host(self.vals)
+            self._host_pres = to_host(self.pres)
             self._host_dirty = False
         return self._host_vals, self._host_pres
 
@@ -359,7 +380,8 @@ class DeviceStateFleet:
                     pres_cols: np.ndarray) -> None:
         cols, sink = self._cols(rows)
         idx, v, p = _pad_cols(cols, sink, vals_cols, pres_cols)
-        self.vals, self.pres = _set_cols(self.vals, self.pres, idx, v, p)
+        self.vals, self.pres = _set_cols(self.vals, self.pres, to_device(idx),
+                                         to_device(v), to_device(p))
         self._host_dirty = True
 
     def clear_rows(self, rows: np.ndarray) -> None:

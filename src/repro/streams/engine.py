@@ -60,6 +60,7 @@ import numpy as np
 
 from repro.core.balancer import Assignment, KeyStats, metrics
 from repro.core.controller import RebalanceController
+from repro.core.telemetry import count, span
 
 from .backends import SKETCH_PENDING, resolve_backend
 from .operators import Operator
@@ -201,10 +202,8 @@ class KeyedStage:
                 f"canonical fmix32); got {type(router).__name__}. ModHash's "
                 "splitmix64 has no 32-bit kernel equivalent.")
         import jax                                    # lazy: numpy path stays jax-free
-        import jax.numpy as jnp
         from repro.kernels.key_stats import key_stats
         from repro.kernels.routing_lookup import routing_lookup
-        self._jnp = jnp
         self._kernel_route = routing_lookup
         self._kernel_stats = key_stats
         self._hash_seed = router.seed
@@ -242,7 +241,10 @@ class KeyedStage:
         """Controller-invoked: the backend moves the state, the stage books
         the stall and opens the pause window for Delta(F, F')."""
         keys = np.asarray(moved_keys, dtype=np.int64)
-        self._migrated_bytes_pending += self.backend.migrate(keys, old, new)
+        with span("migrate"):
+            moved_bytes = self.backend.migrate(keys, old, new)
+        self._migrated_bytes_pending += moved_bytes
+        count("migrated_keys", keys.size)
         # the reference loop materializes the membership set lazily; the
         # vectorized backends only ever consult the array (np.isin)
         self._pending_delta = None
@@ -322,19 +324,19 @@ class KeyedStage:
             # controller and is not supported by this cache.)
             cache_key = (self.controller.assignment_version,
                          assignment.table_size, self._table_capacity)
+            from .device import to_device, to_host  # lazy: jax-only
             if self._route_cache is None or self._route_cache[0] != cache_key:
                 tk, td = assignment.table_arrays(self._table_capacity)
-                self._route_cache = (
-                    cache_key,
-                    self._jnp.asarray(tk.astype(np.int32)),
-                    self._jnp.asarray(td.astype(np.int32)))
+                self._route_cache = (cache_key,
+                                     to_device(tk.astype(np.int32)),
+                                     to_device(td.astype(np.int32)))
             _, tk_dev, td_dev = self._route_cache
             out = self._kernel_route(
-                self._jnp.asarray(keys.astype(np.int32)),
+                to_device(keys.astype(np.int32)),
                 tk_dev, td_dev,
                 assignment.n_dest, seed=self._hash_seed,
                 interpret=self._kernel_interpret)
-            return np.asarray(out).astype(np.int64)
+            return to_host(out).astype(np.int64)
         return self.controller.assignment.dest(keys)
 
     def _finish_interval(self, iv: int, n: int, task_cost: np.ndarray,

@@ -75,19 +75,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.core.telemetry import count, span
 from repro.kernels.routing_lookup import _fmix32
 from repro.launch.mesh import make_mesh
 
 from .backends import DeviceBackend, register_backend
-from .device import DeviceStateFleet
+from .device import DeviceStateFleet, to_device, to_host
 from .state import ColumnarSpec
 
 _INT32_MIN = np.iinfo(np.int32).min
-
-#: python-side-effect trace counters (same pattern as streams/device.py):
-#: increments run at TRACE time only, so tests can assert the sharded step
-#: compiles once across intervals and once per route refresh shape.
-TRACE_COUNTS = {"interval_step": 0, "route_dense": 0}
 
 
 def _build_step_add(mesh, S: int, B: int):
@@ -95,7 +91,7 @@ def _build_step_add(mesh, S: int, B: int):
     L = B + 1
 
     def body(vals, pres, kchunk, cur_col, keep_cols):
-        TRACE_COUNTS["interval_step"] += 1
+        count("retrace.sharded.interval_step")
         k = kchunk[0]                                  # this device's chunk
         valid = k >= 0
         t = jnp.where(valid, k // B, 0)
@@ -130,7 +126,7 @@ def _build_step_max(mesh, S: int, B: int):
     L = B + 1
 
     def body(vals, pres, kchunk, vchunk, cur_col, keep_cols):
-        TRACE_COUNTS["interval_step"] += 1
+        count("retrace.sharded.interval_step")
         k = kchunk[0]
         v = vchunk[0]
         valid = k >= 0
@@ -174,7 +170,7 @@ def _build_route(mesh, S: int, B: int, n_dest: int, seed: int):
     L = B + 1
 
     def body(tk, td):
-        TRACE_COUNTS["route_dense"] += 1
+        count("retrace.sharded.route_dense")
         me = jax.lax.axis_index("shard").astype(jnp.int32)
         kid = me * B + jnp.arange(L, dtype=jnp.int32)
         h = _fmix32(kid.astype(jnp.uint32) ^ jnp.uint32(seed & 0xFFFFFFFF))
@@ -230,7 +226,7 @@ class ShardedStateFleet(DeviceStateFleet):
 
     def _to_dense_1d(self, garr) -> np.ndarray:
         """(S*(B+1),) global output -> host key-dense (domain+1,)."""
-        a = np.asarray(garr)
+        a = to_host(garr)
         if self._block == 0:           # domain never grown: nothing held yet
             return np.zeros(self.domain + 1, a.dtype)
         L = self._block + 1
@@ -273,8 +269,8 @@ class ShardedStateFleet(DeviceStateFleet):
             task[:old_dom] = self.task[:old_dom]
             mem[:old_dom] = self.mem[:old_dom]
         self.domain = dom
-        self.vals = jax.device_put(vals, self._sharding)
-        self.pres = jax.device_put(pres, self._sharding)
+        self.vals = to_device(vals, self._sharding)
+        self.pres = to_device(pres, self._sharding)
         self.task, self.mem = task, mem
         self._all_keys = None
         self._host_dirty = True
@@ -286,7 +282,7 @@ class ShardedStateFleet(DeviceStateFleet):
         flat = np.full(self.n_shards * cap, pad, dtype=np.int32)
         if n:
             flat[:n] = arr
-        return jax.device_put(flat.reshape(self.n_shards, cap), self._chunks)
+        return to_device(flat.reshape(self.n_shards, cap), self._chunks)
 
     def interval_step(self, keys: np.ndarray, tuple_vals: Optional[np.ndarray],
                       dest_dense, n_tasks: int, keep_cols: np.ndarray,
@@ -295,31 +291,32 @@ class ShardedStateFleet(DeviceStateFleet):
         ``task_counts`` slot is always None (no built-in operator is
         max-mode AND unit-cost, so the engine derives per-task loads from
         counts + the host dest mirror — see backends.DeviceBackend)."""
-        S = self.n_shards
-        n = int(keys.shape[0])
-        per = -(-n // S) if n else 1
-        if per > self._chunk_cap:
-            self._chunk_cap = max(256, 1 << (per - 1).bit_length())
-        cap = self._chunk_cap
-        kchunk = self._chunk(keys, n, -1, cap)
-        fn_key = (mode, self._block)
-        fn = self._step_fns.get(fn_key)
-        if fn is None:
-            build = _build_step_add if mode == "add" else _build_step_max
-            fn = build(self.mesh, S, self._block)
-            self._step_fns[fn_key] = fn
-        cur = jax.device_put(cur_col, self._replicated)
-        keep = jax.device_put(keep_cols, self._replicated)
-        if mode == "add":
-            out = fn(self.vals, self.pres, kchunk, cur, keep)
-        else:
-            vchunk = self._chunk(tuple_vals, n, _INT32_MIN, cap)
-            out = fn(self.vals, self.pres, kchunk, vchunk, cur, keep)
-        self.vals, self.pres = out[0], out[1]
-        self._host_dirty = True
-        return (self._to_dense_1d(out[2]), self._to_dense_1d(out[3]),
-                self._to_dense_1d(out[4]), self._to_dense_1d(out[5]),
-                self._to_dense_1d(out[6]), None)
+        with span("step"):
+            S = self.n_shards
+            n = int(keys.shape[0])
+            per = -(-n // S) if n else 1
+            if per > self._chunk_cap:
+                self._chunk_cap = max(256, 1 << (per - 1).bit_length())
+            cap = self._chunk_cap
+            kchunk = self._chunk(keys, n, -1, cap)
+            fn_key = (mode, self._block)
+            fn = self._step_fns.get(fn_key)
+            if fn is None:
+                build = _build_step_add if mode == "add" else _build_step_max
+                fn = build(self.mesh, S, self._block)
+                self._step_fns[fn_key] = fn
+            cur = to_device(cur_col, self._replicated)
+            keep = to_device(keep_cols, self._replicated)
+            if mode == "add":
+                out = fn(self.vals, self.pres, kchunk, cur, keep)
+            else:
+                vchunk = self._chunk(tuple_vals, n, _INT32_MIN, cap)
+                out = fn(self.vals, self.pres, kchunk, vchunk, cur, keep)
+            self.vals, self.pres = out[0], out[1]
+            self._host_dirty = True
+            return (self._to_dense_1d(out[2]), self._to_dense_1d(out[3]),
+                    self._to_dense_1d(out[4]), self._to_dense_1d(out[5]),
+                    self._to_dense_1d(out[6]), None)
 
     def evict(self, keep_cols: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         cnt, tot = super().evict(keep_cols)     # global (S*(B+1),) outputs
@@ -338,8 +335,8 @@ class ShardedStateFleet(DeviceStateFleet):
             fn = _build_route(self.mesh, self.n_shards, self._block,
                               int(n_dest), int(seed))
             self._route_fns[fn_key] = fn
-        return fn(jax.device_put(tkeys.astype(np.int32), self._replicated),
-                  jax.device_put(tdests.astype(np.int32), self._replicated))
+        return fn(to_device(tkeys.astype(np.int32), self._replicated),
+                  to_device(tdests.astype(np.int32), self._replicated))
 
     def dest_host_dense(self, dev) -> np.ndarray:
         return self._to_dense_1d(dev).astype(np.int64)
@@ -347,8 +344,8 @@ class ShardedStateFleet(DeviceStateFleet):
     # -- host snapshots (pack contract + introspection) -------------------------
     def host_state(self) -> Tuple[np.ndarray, np.ndarray]:
         if self._host_dirty:
-            self._host_vals = self._to_dense_2d(np.asarray(self.vals))
-            self._host_pres = self._to_dense_2d(np.asarray(self.pres))
+            self._host_vals = self._to_dense_2d(to_host(self.vals))
+            self._host_pres = self._to_dense_2d(to_host(self.pres))
             self._host_dirty = False
         return self._host_vals, self._host_pres
 

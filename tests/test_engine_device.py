@@ -186,13 +186,15 @@ def test_no_retrace():
     retrace. ``scale_to`` bumps ``n_dest`` (static in the dense route), so
     the route recompiles exactly once; the add-mode step is allowed no
     retrace at all."""
-    from repro.streams import device as dev_mod
+    from repro.core.telemetry import COUNTERS
 
     # jit caches are module-level, so this test must not share a trace
     # signature with any other test in the process: window=5 (unique ring
     # width), n_tasks=6 -> 9 (unique static args) and hash seed 99 (static
     # in the dense-route trace) make the counts order-independent.
-    base = dict(dev_mod.TRACE_COUNTS)
+    step = "retrace.device.interval_step"
+    route = "retrace.device.route_dense"
+    base = {k: COUNTERS.get(k, 0) for k in (step, route)}
     stage = make_stage(WordCount(), "device", n_tasks=6, window=5,
                        theta_max=0.03, seed=99)
     gen = WorkloadGen(k=400, z=1.1, f=0.8, seed=3, window=5)
@@ -204,21 +206,21 @@ def test_no_retrace():
     # at least one rebalance actually happened, so the no-retrace claim is
     # exercised against a moving assignment, not a static one
     assert stage.controller.assignment.table_size > 0
-    d6 = {k: dev_mod.TRACE_COUNTS[k] - base[k] for k in base}
-    assert d6["interval_step"] == 1, d6
-    assert d6["route_dense"] == 1, d6
+    d6 = {k: COUNTERS.get(k, 0) - base[k] for k in base}
+    assert d6[step] == 1, d6
+    assert d6[route] == 1, d6
 
     stage.scale_to(9)
     for i in range(6, 10):
         gen.interval(stage.controller.assignment)
         stage.process_interval_arrays(gen.draw_tuples(1000).astype(np.int64),
                                       np.full(1000, i))
-    d10 = {k: dev_mod.TRACE_COUNTS[k] - base[k] for k in base}
+    d10 = {k: COUNTERS.get(k, 0) - base[k] for k in base}
     # rescale: the add-mode step is shape-only (the task count lives in the
     # dense dest table and host mirrors), so it must NOT retrace; the dense
     # route is static in n_dest, so it retraces exactly once
-    assert d10["interval_step"] == 1, d10
-    assert d10["route_dense"] == 2, d10
+    assert d10[step] == 1, d10
+    assert d10[route] == 2, d10
 
 
 # -- backend selection + validation ------------------------------------------

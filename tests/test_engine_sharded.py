@@ -262,11 +262,13 @@ def test_no_retrace_sharded():
     step carries no n_tasks static at all, so even ``scale_to`` leaves it
     alone. The per-shard route refresh recompiles exactly once per
     ``n_dest`` change (rescale)."""
-    from repro.streams import sharded as sh_mod
+    from repro.core.telemetry import COUNTERS
 
     # the sharded jit wrappers are per-fleet (not module-level), so a fresh
     # stage always contributes exactly its own traces to the counters
-    base = dict(sh_mod.TRACE_COUNTS)
+    step = "retrace.sharded.interval_step"
+    route = "retrace.sharded.route_dense"
+    base = {k: COUNTERS.get(k, 0) for k in (step, route)}
     stage = make_stage(WordCount(), "sharded", n_tasks=6, window=5,
                        theta_max=0.03, seed=99)
     gen = WorkloadGen(k=400, z=1.1, f=0.8, seed=3, window=5)
@@ -278,18 +280,18 @@ def test_no_retrace_sharded():
     # at least one rebalance actually happened, so the no-retrace claim is
     # exercised against a moving assignment, not a static one
     assert stage.controller.assignment.table_size > 0
-    d6 = {k: sh_mod.TRACE_COUNTS[k] - base[k] for k in base}
-    assert d6["interval_step"] == 1, d6
-    assert d6["route_dense"] == 1, d6
+    d6 = {k: COUNTERS.get(k, 0) - base[k] for k in base}
+    assert d6[step] == 1, d6
+    assert d6[route] == 1, d6
 
     stage.scale_to(9)
     for i in range(6, 10):
         gen.interval(stage.controller.assignment)
         stage.process_interval_arrays(gen.draw_tuples(1000).astype(np.int64),
                                       np.full(1000, i))
-    d10 = {k: sh_mod.TRACE_COUNTS[k] - base[k] for k in base}
-    assert d10["interval_step"] == 1, d10
-    assert d10["route_dense"] == 2, d10
+    d10 = {k: COUNTERS.get(k, 0) - base[k] for k in base}
+    assert d10[step] == 1, d10
+    assert d10[route] == 2, d10
 
 
 # -- backend selection + validation ------------------------------------------
