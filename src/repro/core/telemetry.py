@@ -8,15 +8,16 @@ no switch: spans are recorded exactly when a profiler session runs. A
 process that never imported jax can have no session, so there the span is a
 no-op and this module keeps the host-only paths jax-free.
 
-Spans nest: ``pull`` may open inside ``route`` or ``step``; every other span
-opens at most inside the stage's entry call. What each covers:
+Spans nest: ``pull`` may open inside ``step`` (the step's readback) or
+``finish`` (the route table's deferred copy); every other span opens at
+most inside the stage's entry call. What each covers:
 
 =========  ==============================================================
 span       work
 =========  ==============================================================
 pause      the buffered count of the pause window after a migration
-route      a dense route refresh (table arrays, route, host copy); its
-           ``table`` stat is the padded table capacity
+route      a dense route refresh (table arrays, route, start of its host
+           copy); its ``table`` stat is the padded table capacity
 step       host histogram, uploads and dispatch of the fused step
 pull       one device-to-host copy; its ``bytes`` stat is the array's size
 finish     closed forms, outputs, emitted sum, task cost, host mirrors
@@ -30,8 +31,10 @@ migrate    the relabel of the keys a plan moved
 (every transfer the stream engine makes, through
 ``repro.streams.device.to_host``/``to_device``), ``route_refreshes``,
 ``plans``, ``migrated_keys`` (``bench/spanreduce.py`` prints each per
-interval), and ``retrace.<module>.<function>`` (incremented while a jitted
-function is traced, so a test can see that it compiled once).
+interval), ``d2h_copies`` (one per device-to-host copy, whether or not it
+was started ahead; ``bench/spanreduce.py``'s ``counters`` hold its change
+over the window), and ``retrace.<module>.<function>`` (incremented while a
+jitted function is traced, so a test can see that it compiled once).
 """
 
 from __future__ import annotations

@@ -531,7 +531,8 @@ class DeviceBackend(StateBackend):
         super().__init__(stage)
         self._device_seed = stage.controller.assignment.hash_router.seed
         self._fleet = self._make_fleet()
-        self._dest_dense_cache = None   # (cache key, device dests, host dests)
+        # (cache key, device dests, host dests or None until first read)
+        self._dest_dense_cache = None
         self._views_made = 0
 
     def _make_fleet(self):
@@ -650,11 +651,15 @@ class DeviceBackend(StateBackend):
             store.install_batch(pack.clone())
 
     # -- dense routing table ---------------------------------------------------
-    def _dest_dense_arrays(self):
+    def _dest_dense(self):
         """Dense F(k) table over every key id, refreshed once per
         ``assignment_version`` (and per domain growth) — the device twin of
         the pallas substrate's routing-table cache, sharing its power-of-two
-        high-water table capacity so table churn never retraces."""
+        high-water table capacity so table churn never retraces.
+
+        A refresh starts the table's host copy and does not wait for it:
+        :meth:`_dest_host` materializes it after the step's readback, by
+        when the copy has run behind the histogram, uploads and step."""
         stage = self.stage
         assignment = stage.controller.assignment
         needed = max(128, 1 << max(0, assignment.table_size - 1).bit_length())
@@ -671,10 +676,19 @@ class DeviceBackend(StateBackend):
                     tk, td, assignment.n_dest, seed=self._device_seed,
                     use_kernel=(stage.substrate == "pallas"),
                     interpret=stage._kernel_interpret)
-                self._dest_dense_cache = (cache_key, dev,
-                                          self._fleet.dest_host_dense(dev))
+                dev.copy_to_host_async()
+                self._dest_dense_cache = (cache_key, dev, None)
             count("route_refreshes")
-        return self._dest_dense_cache[1], self._dest_dense_cache[2]
+        return self._dest_dense_cache[1]
+
+    def _dest_host(self) -> np.ndarray:
+        """Host copy of the cached dense table (``fleet.dest_host_dense``),
+        pulled on its first read after a refresh and reused on cache hits."""
+        cache_key, dev, host = self._dest_dense_cache
+        if host is None:
+            host = self._fleet.dest_host_dense(dev)
+            self._dest_dense_cache = (cache_key, dev, host)
+        return host
 
     # -- one interval as ONE fused device step ---------------------------------
     def process_interval(self, keys: np.ndarray,
@@ -732,7 +746,7 @@ class DeviceBackend(StateBackend):
                     "allocates state per key id — raise device_domain_max or "
                     "use the columnar backend for sparse huge domains")
             fleet.ensure_domain(kmax + 1)
-            dest_dev, dest_host = self._dest_dense_arrays()
+            dest_dev = self._dest_dense()
             cur = np.zeros(w1, dtype=np.int32)
             cur[c] = 1
             tv = None
@@ -749,9 +763,10 @@ class DeviceBackend(StateBackend):
                                        keep, cur, op.device_mode)
             dom = fleet.domain
             counts_h, win0_h, slot0_h, held_cnt, held_sum = (
-                to_host(a)[:dom] for a in step[:5])
+                a[:dom] for a in step[:5])
 
             with span("finish"):
+                dest_host = self._dest_host()
                 seen_mask = counts_h > 0
                 gk = np.nonzero(seen_mask)[0].astype(np.int64)
                 key_cost_g, out_vals, emit_sum = op.device_finish(

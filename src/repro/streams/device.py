@@ -88,14 +88,16 @@ _INT32_MIN = np.iinfo(np.int32).min
 
 def to_host(x) -> np.ndarray:
     """Host copy of a device array, as one ``pull`` span counted in
-    ``d2h_bytes`` (its time includes waiting for the step that makes
-    ``x``). A host array passes through untouched."""
+    ``d2h_bytes`` and ``d2h_copies`` (its time includes waiting for the
+    step that makes ``x``, or for a copy ``copy_to_host_async`` started).
+    A host array passes through untouched."""
     if isinstance(x, np.ndarray):
         return x
     n = int(x.nbytes)
     with span("pull", bytes=n):
         out = np.asarray(x)
     count("d2h_bytes", n)
+    count("d2h_copies")
     return out
 
 
@@ -125,10 +127,12 @@ def _interval_step_add(vals, pres, counts, cur_col, keep_cols):
       cur_col:   (W1,) int32 one-hot of this interval's ring column.
       keep_cols: (W1,) int32 0/1 — columns surviving this boundary's eviction.
 
-    Returns the post-boundary state plus the integer observables the host
-    closed forms need: window/slot totals BEFORE the update, then per-key
-    held slot-count and value-sum AFTER eviction. In add mode the slot
-    delta IS the count, so the whole update is elementwise.
+    Returns the post-boundary state plus ``obs``, the integer observables
+    the host closed forms need stacked into one (4, D+1) int32 array so
+    the host pulls them in one copy: rows window/slot totals BEFORE the
+    update, then per-key held slot-count and value-sum AFTER eviction. In
+    add mode the slot delta IS the count, so the whole update is
+    elementwise.
     """
     count("retrace.device.interval_step")
     win0 = vals.sum(axis=0)
@@ -138,7 +142,8 @@ def _interval_step_add(vals, pres, counts, cur_col, keep_cols):
     pres = jnp.maximum(pres, cur_col[:, None] * seen[None, :])
     vals = vals * keep_cols[:, None]
     pres = pres * keep_cols[:, None]
-    return vals, pres, win0, slot0, pres.sum(axis=0), vals.sum(axis=0)
+    obs = jnp.stack([win0, slot0, pres.sum(axis=0), vals.sum(axis=0)])
+    return vals, pres, obs
 
 
 @functools.partial(jax.jit, static_argnames=("n_tasks",),
@@ -155,10 +160,11 @@ def _interval_step_max(vals, pres, keys, tvals, dest_dense, cur_col,
       cur_col:   (W1,) int32 one-hot of this interval's ring column.
       keep_cols: (W1,) int32 0/1 — columns surviving this boundary's eviction.
 
-    Returns the post-boundary state plus per-key counts, window/slot totals
-    BEFORE the update, held slot-count and value-sum AFTER eviction, and the
-    per-task tuple bincount. Unlike add mode the fold genuinely needs the
-    raw tuple values, so the scatters stay on-device.
+    Returns the post-boundary state, ``obs`` — one (5, D+1) int32 array
+    with rows per-key counts, window/slot totals BEFORE the update, held
+    slot-count and value-sum AFTER eviction — and the per-task tuple
+    bincount. Unlike add mode the fold genuinely needs the raw tuple
+    values, so the scatters stay on-device.
     """
     count("retrace.device.interval_step")
     d1 = vals.shape[1]
@@ -174,19 +180,20 @@ def _interval_step_max(vals, pres, keys, tvals, dest_dense, cur_col,
     pres = jnp.maximum(pres, cur_col[:, None] * seen[None, :])
     vals = vals * keep_cols[:, None]
     pres = pres * keep_cols[:, None]
-    held_cnt = pres.sum(axis=0)
-    held_sum = vals.sum(axis=0)
+    obs = jnp.stack([counts, win0, slot0, pres.sum(axis=0),
+                     vals.sum(axis=0)])
     task_counts = jnp.zeros((n_tasks,), jnp.int32).at[dest_dense].add(counts)
-    return vals, pres, counts, win0, slot0, held_cnt, held_sum, task_counts
+    return vals, pres, obs, task_counts
 
 
 @functools.partial(jax.jit, donate_argnums=_DONATE)
 def _evict_step(vals, pres, keep_cols):
-    """Boundary eviction for a tuple-free interval (no slot updates)."""
+    """Boundary eviction for a tuple-free interval (no slot updates); the
+    held slot-count and value-sum come stacked, as one (2, D+1) array."""
     count("retrace.device.evict_step")
     vals = vals * keep_cols[:, None]
     pres = pres * keep_cols[:, None]
-    return vals, pres, pres.sum(axis=0), vals.sum(axis=0)
+    return vals, pres, jnp.stack([pres.sum(axis=0), vals.sum(axis=0)])
 
 
 @functools.partial(jax.jit, donate_argnums=_DONATE)
@@ -290,12 +297,15 @@ class DeviceStateFleet:
     def interval_step(self, keys: np.ndarray, tuple_vals: Optional[np.ndarray],
                       dest_dense, n_tasks: int, keep_cols: np.ndarray,
                       cur_col: np.ndarray, mode: str):
-        """Run one interval's fused step.
+        """Run one interval's fused step: host arrays in, host arrays out.
 
-        Returns ``(counts, win0, slot0, held_cnt, held_sum, task_counts)``;
-        ``counts`` is a host int32 array in add mode (where the histogram is
-        computed host-side — see the module docstring) and ``task_counts``
-        is None there (derive it from counts + the host dest mirror).
+        Returns ``(counts, win0, slot0, held_cnt, held_sum, task_counts)``,
+        the first five host int32 ``(D+1,)`` arrays from ONE device-to-host
+        copy of the step's stacked observables (in add mode ``counts`` is
+        the host histogram — see the module docstring — and is not pulled).
+        ``task_counts`` is None in add mode (derive it from counts + the
+        host dest mirror); in max mode it stays on the device, for the
+        caller to pull only if its operator needs it.
         """
         with span("step"):
             if mode == "add":
@@ -306,7 +316,7 @@ class DeviceStateFleet:
                                          to_device(keep_cols))
                 self.vals, self.pres = out[0], out[1]
                 self._host_dirty = True
-                return (counts,) + tuple(out[2:]) + (None,)
+                return (counts, *to_host(out[2]), None)
             n = int(keys.shape[0])
             if n > self._keys_cap:
                 self._keys_cap = max(1024, 1 << (n - 1).bit_length())
@@ -323,13 +333,14 @@ class DeviceStateFleet:
                                      n_tasks=n_tasks)
             self.vals, self.pres = out[0], out[1]
             self._host_dirty = True
-            return out[2:]
+            return (*to_host(out[2]), out[3])
 
     def evict(self, keep_cols: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         out = _evict_step(self.vals, self.pres, to_device(keep_cols))
         self.vals, self.pres = out[0], out[1]
         self._host_dirty = True
-        return to_host(out[2]), to_host(out[3])
+        held_cnt, held_sum = to_host(out[2])
+        return held_cnt, held_sum
 
     def route_dense(self, tkeys: np.ndarray, tdests: np.ndarray, n_dest: int,
                     seed: int, use_kernel: bool,

@@ -223,6 +223,194 @@ def test_no_retrace():
     assert d10[route] == 2, d10
 
 
+@pytest.mark.parametrize("op_kind", ["add", "max"])
+def test_stacked_steps_trace_once_per_domain(op_kind):
+    """Each fused step (and the tuple-free eviction step) returns its per-key
+    observables as ONE stacked array: it traces once per dense domain, and
+    neither rebalances nor idle intervals retrace it."""
+    from repro.core.telemetry import COUNTERS
+
+    # window 6 (add) and 7 (max) are ring widths no other test uses, so the
+    # module-level jit caches start cold for these shapes
+    window = 6 if op_kind == "add" else 7
+    op = WordCount() if op_kind == "add" else MergeCounts()
+    names = ("retrace.device.interval_step", "retrace.device.evict_step")
+    base = {k: COUNTERS.get(k, 0) for k in names}
+
+    def traces():
+        return tuple(COUNTERS.get(k, 0) - base[k] for k in names)
+
+    stage = make_stage(op, "device", window=window, theta_max=0.03, seed=5)
+    rng = np.random.default_rng(7)
+
+    def run(hi, n=600):
+        keys = (rng.zipf(1.3, size=n) % hi).astype(np.int64)
+        stage.process_interval_arrays(keys, rng.integers(1, 50, size=n))
+
+    for _ in range(4):
+        run(400)                                 # domain 512
+    assert stage.controller.assignment_version > 0   # it did rebalance
+    assert traces() == (1, 0)
+    for _ in range(window):                      # idle: the ring empties
+        stage.process_interval_arrays(np.zeros(0, np.int64), np.zeros(0))
+    assert stage.total_state_keys() == 0
+    assert traces() == (1, 1)
+    run(400)
+    run(1000)                                    # domain grows to 1024
+    run(1000)
+    assert stage.backend._fleet.domain == 1024
+    assert traces() == (2, 1)
+
+
+def _record_device_spans(monkeypatch):
+    """Every span ``repro.streams.device`` opens, as (name, stats)."""
+    from repro.streams import device as dev_mod
+
+    opened = []
+    real = dev_mod.span
+
+    def span(name, **args):
+        opened.append((name, args))
+        return real(name, **args)
+    monkeypatch.setattr(dev_mod, "span", span)
+    return opened
+
+
+def _refresh_stage(op):
+    """A stage whose plans refresh the dense route on some intervals and
+    hit its cache on others (theta_max 0.15 over a settling Zipf stream)."""
+    return (make_stage(op, "device", theta_max=0.15),
+            WorkloadGen(k=400, z=1.1, f=0.8, seed=3, window=3))
+
+
+@pytest.mark.parametrize("op_kind,rows", [("add", 4), ("max", 5)])
+def test_one_step_copy_per_interval(op_kind, rows, monkeypatch):
+    """An interval with tuples pulls the step's observables in ONE copy of
+    ``rows`` (D+1,) int32 planes (add mode keeps its histogram on the host),
+    plus one copy of the dense route table when the route refreshed and
+    none on a cache hit; ``d2h_copies`` and ``d2h_bytes`` count exactly
+    those copies."""
+    from repro.core.telemetry import COUNTERS
+
+    opened = _record_device_spans(monkeypatch)
+    stage, gen = _refresh_stage(WordCount() if op_kind == "add"
+                                else MergeCounts())
+    watched = ("route_refreshes", "d2h_copies", "d2h_bytes")
+    first = {k: COUNTERS.get(k, 0) for k in watched}
+    rng = np.random.default_rng(11)
+    refreshes = []
+    for i in range(8):
+        if i in (1, 2, 3):
+            gen.interval(stage.controller.assignment)
+        keys = gen.draw_tuples(5000).astype(np.int64)
+        before = {k: COUNTERS.get(k, 0) for k in watched}
+        opened.clear()
+        stage.process_interval_arrays(keys, rng.integers(1, 50, size=5000))
+        delta = {k: COUNTERS.get(k, 0) - before[k] for k in watched}
+        width = (stage.backend._fleet.domain + 1) * 4
+        pulls = [args["bytes"] for name, args in opened if name == "pull"]
+        assert pulls.count(rows * width) == 1, pulls
+        assert pulls.count(width) == delta["route_refreshes"], pulls
+        assert len(pulls) == 1 + delta["route_refreshes"], pulls
+        assert delta["d2h_copies"] == 1 + delta["route_refreshes"]
+        assert delta["d2h_bytes"] == width * (rows + delta["route_refreshes"])
+        refreshes.append(delta["route_refreshes"])
+    assert set(refreshes) == {0, 1}, refreshes   # refreshes and cache hits
+    total = {k: COUNTERS.get(k, 0) - first[k] for k in watched}
+    assert total["d2h_bytes"] == width * (rows * 8 + sum(refreshes))
+
+
+def _route_dense_reference(stage) -> np.ndarray:
+    """``device._route_dense`` over the fleet's whole domain for the
+    stage's current assignment, on the host."""
+    import jax.numpy as jnp
+
+    from repro.streams import device as dev_mod
+
+    assignment = stage.controller.assignment
+    tk, td = assignment.table_arrays()
+    d1 = stage.backend._fleet.domain + 1
+    return np.asarray(dev_mod._route_dense(
+        jnp.arange(d1, dtype=jnp.int32), jnp.asarray(tk.astype(np.int32)),
+        jnp.asarray(td.astype(np.int32)), n_dest=assignment.n_dest,
+        seed=stage.backend._device_seed))
+
+
+def test_deferred_dest_copy_equals_the_dense_route():
+    """A refresh starts the dest table's host copy and caches no host array;
+    the first read materializes it, element for element ``_route_dense``'s
+    output for the assignment it was refreshed under, and a cache hit reuses
+    that same host array."""
+    stage, gen = _refresh_stage(WordCount())
+    backend = stage.backend
+    hits = refreshed = 0
+    host_prev = None
+    for i in range(8):
+        if i in (1, 2, 3):
+            gen.interval(stage.controller.assignment)
+        keys = gen.draw_tuples(5000).astype(np.int64)
+        stage.backend._fleet.ensure_domain(int(keys.max()) + 1)
+        expected = _route_dense_reference(stage)
+        key_before = (None if backend._dest_dense_cache is None
+                      else backend._dest_dense_cache[0])
+        backend._dest_dense()                 # what the interval does first
+        cache_key, _, host = backend._dest_dense_cache
+        if cache_key == key_before:           # a hit: the pulled copy stays
+            assert host is host_prev
+            hits += 1
+        else:                                 # a refresh: nothing pulled yet
+            assert host is None
+            refreshed += 1
+        stage.process_interval_arrays(keys)
+        host = backend._dest_dense_cache[2]
+        assert host.dtype == np.int64
+        np.testing.assert_array_equal(host, expected)
+        assert backend._dest_host() is host
+        host_prev = host
+    assert hits and refreshed
+
+
+def test_restore_never_reads_a_stale_dest_copy():
+    """``restore`` drops the dest cache, host copy and all. The controller's
+    version rewinds on a restore, so two branches of one run can reach the
+    same cache key (version, table size, capacity, domain, tasks) with
+    different tables; after a restore onto one branch the stage must read
+    that branch's table, never the host copy the other branch cached."""
+    from repro.streams.checkpoint import checkpoint_stage, restore_stage
+
+    stage = make_stage(WordCount(), "device", theta_max=0.05)
+    rng = np.random.default_rng(0)
+    keys = (rng.zipf(1.3, 5000) % 400).astype(np.int64)
+    swapped = keys.copy()           # keys 1 and 2 trade their frequencies
+    swapped[keys == 1], swapped[keys == 2] = 2, 1
+
+    def table():
+        return (stage.controller.assignment_version,
+                dict(stage.controller.assignment.table))
+
+    stage.process_interval_arrays(keys[:200] % 50)
+    start = checkpoint_stage(stage)
+    stage.process_interval_arrays(keys)               # branch A plans
+    branch_a = checkpoint_stage(stage)
+    version_a, table_a = table()
+    restore_stage(stage, start)
+    stage.process_interval_arrays(swapped)            # branch B plans
+    version_b, table_b = table()
+    assert version_a == version_b and len(table_a) == len(table_b)
+    assert table_a != table_b
+    stage.process_interval_arrays(keys)               # caches B's table
+    stale = stage.backend._dest_dense_cache[2]
+
+    restore_stage(stage, branch_a)
+    assert stage.backend._dest_dense_cache is None
+    assert table() == (version_a, table_a)
+    expected = _route_dense_reference(stage)
+    assert (stale != expected).any()
+    stage.process_interval_arrays(keys)
+    np.testing.assert_array_equal(stage.backend._dest_dense_cache[2],
+                                  expected)
+
+
 # -- backend selection + validation ------------------------------------------
 
 def _hash32_controller(n_tasks=4, seed=0):
