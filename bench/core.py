@@ -4,11 +4,12 @@ Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
 its configuration in ``configs/<config>.json`` (which names a pipeline in
 ``pipelines/<pipeline>.py`` and its plain reference in
 ``pipelines/<pipeline>.reference.py``), its traffic in
-``traffic/<mix>.json``, each metric's reader in ``metrics/<metric>.py`` (or, for a metric
+``traffic/<mix>.json``, drawn by ``source.py`` or, where the configuration
+names a ``traffic_source``, by ``sources/<traffic_source>.py``, each
+metric's reader in ``metrics/<metric>.py`` (or, for a metric
 ``<name>.<suffix>`` split by the end-to-end metric it moves, the shared
-``metrics/<name>.py``),
-each kernel's work function in ``kernels/<kernel>.py`` and the chip's peaks
-in ``peaks.json``. A run:
+``metrics/<name>.py``), each kernel's work function in
+``kernels/<kernel>.py`` and the chip's peaks in ``peaks.json``. A run:
 
 1. refuses a machine whose first device is not a TPU of a known kind, or
    with fewer chips than the cell asks for;
@@ -21,6 +22,10 @@ in ``peaks.json``. A run:
 5. reads the program's answers, frees it, and compares them with the plain
    reference; prints the numbers compared, each beside its limit, and the
    result line.
+
+A traced run also hands the readers the device trace's summary
+(``tracereduce``), the program spans' self times (``spanreduce``) and the
+change of the program's counters over the window.
 """
 
 from __future__ import annotations
@@ -37,7 +42,7 @@ import tempfile
 import time
 import traceback
 from pathlib import Path
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -149,7 +154,9 @@ class Run:
     t_close: float
     intervals: List[Interval]
     plan_s: float = 0.0
-    summary: object = None          # trace.Summary of a traced run
+    summary: object = None          # tracereduce.Summary of a traced run
+    spans: object = None            # spanreduce.SpanSummary of a traced run
+    counters: Optional[Dict[str, int]] = None   # change over the window
     kernel_shapes: dict = dataclasses.field(default_factory=dict)
     peaks: dict = dataclasses.field(default_factory=dict)
 
@@ -211,13 +218,24 @@ def run_window(system, pool: Sequence[np.ndarray], seconds: float,
     return t0, t_close, intervals, error
 
 
+def traffic_source(cfg: dict):
+    """The module that draws the configuration's traffic: ``source`` or
+    ``sources/<traffic_source>.py``."""
+    if "traffic_source" not in cfg:
+        import source
+        return source
+    name = cfg["traffic_source"]
+    return load_module(BENCH / "sources" / f"{name}.py", f"source_{name}")
+
+
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
              t_start: float, require_tpu: bool = True,
              overrides: Optional[dict] = None,
              traffic_overrides: Optional[dict] = None,
              control: bool = False,
-             bench_file: Path = ROOT / "BENCHMARK.json") -> dict:
-    """One run of cell ``name``; returns the result line's object.
+             bench_file: Path = ROOT / "BENCHMARK.json") -> Tuple[dict, Run]:
+    """One run of cell ``name``; returns the result line's object and what
+    its metrics were read from.
 
     ``overrides``/``traffic_overrides`` replace configuration and traffic
     values (the tests shrink a cell with them). ``require_tpu=False``
@@ -227,6 +245,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     place) and returns its numbers under ``"control"``. ``bench_file``
     is where the cell is defined."""
     import jax
+    from repro.core.telemetry import COUNTERS, SPANS as PROGRAM_SPANS
 
     cell = Cell.load(name, bench_file)
     cfg = dict(cell.config, **(overrides or {}))
@@ -246,7 +265,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     if traffic_overrides:
         mix = dataclasses.replace(mix, **traffic_overrides)
     n_window = mix.window_intervals(seconds, cfg["tuples"])
-    stream = source.traffic(cfg, mix, mix.warmup_intervals + n_window, seed)
+    stream = traffic_source(cfg).traffic(
+        cfg, mix, mix.warmup_intervals + n_window, seed)
     warmup, pool = (stream[:mix.warmup_intervals],
                     stream[mix.warmup_intervals:])
 
@@ -269,10 +289,12 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         span = lambda name: contextlib.nullcontext()  # noqa: E731
     c0 = counter.programs
     setup_s = time.perf_counter() - t_start
+    before = dict(COUNTERS)
     with span("window"):
         t0, t_close, intervals, error = run_window(system, pool, seconds,
                                                    span)
     in_window = counter.programs - c0
+    counters = {k: v - before.get(k, 0) for k, v in COUNTERS.items()}
     if trace:
         jax.profiler.stop_trace()
     print(f"compiles in the window: {in_window} (set-up: {warm_programs} "
@@ -283,7 +305,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     n_done = sum(iv.completed for iv in intervals)
     run = Run(cell, mix, setup_s, t0, t_close, intervals,
               plan_s=system.plan_seconds(n_before + 1, n_before + n_done),
-              kernel_shapes=system.kernel_shapes(), peaks=chip)
+              counters=counters, kernel_shapes=system.kernel_shapes(),
+              peaks=chip)
     memory_peak = (devices[0].memory_stats() or {}).get("peak_bytes_in_use",
                                                         0)
     observed = system.observe() if error is None else None
@@ -318,10 +341,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     result: dict = {}
     if trace:
         from jax.profiler import ProfileData
+        import spanreduce
         import tracereduce
         files = sorted(Path(trace_dir).glob("**/*.xplane.pb"))
-        run.summary = (tracereduce.reduce(
-            ProfileData.from_file(str(files[-1])), SPANS) if files else None)
+        if files:
+            profile = ProfileData.from_file(str(files[-1]))
+            run.summary = tracereduce.reduce(profile, SPANS, PROGRAM_SPANS)
+            run.spans = spanreduce.reduce(profile, PROGRAM_SPANS)
         shutil.rmtree(trace_dir, ignore_errors=True)
         metrics = read_metrics(run, cell.per_layer)
         if run.summary is not None:
@@ -349,4 +375,4 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
               **result,
               "checks": {k: {"value": checks[k], "limit": limits[k]}
                          for k in checks}}
-    return result
+    return result, run

@@ -35,7 +35,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     for seed in args.seeds:
         t = time.perf_counter()
-        r = core.run_cell(args.workload, seed, args.seconds, False,
+        r, _ = core.run_cell(args.workload, seed, args.seconds, False,
                           t_start=t, control=True)
         print(json.dumps({
             "seed": seed, "correct": r["correct"], "metrics": r["metrics"],

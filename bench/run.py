@@ -47,7 +47,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     try:
-        result = core.run_cell(args.workload, args.seed, args.seconds,
+        result, _ = core.run_cell(args.workload, args.seed, args.seconds,
                                bool(args.trace), t_start=T_START)
     except core.Refused as e:
         print(f"refused: {e}", file=sys.stderr)

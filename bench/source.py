@@ -1,10 +1,16 @@
 """The benchmark's traffic: one general generator that every mix file feeds.
 
-The configuration gives the key domain ``K``, the Zipf exponent ``z``, the
-tasks, the hash seed and the tuples per interval. A mix
-(``bench/traffic/<mix>.json``) gives the fluctuation rate ``f``, the number
-of warm-up intervals and ``pool_rate``: the source stays ahead of the
-engine, with a pool of intervals sized for that many tuples per second.
+A configuration that names another generator under ``traffic_source`` is
+fed by ``bench/sources/<name>.py`` instead; its ``traffic(cfg, mix,
+intervals, seed)`` returns the key arrays as :func:`traffic` does. Every mix
+(``bench/traffic/<mix>.json``) gives the number of warm-up intervals,
+``layout_seed`` and ``pool_rate``: the source stays ahead of the engine,
+with a pool of intervals sized for that many tuples per second. Its other
+keys reach the generator as ``Mix.params``, as the file gives them.
+
+This generator takes the key domain ``K``, the Zipf exponent ``z``, the
+tasks, the hash seed and the tuples per interval from the configuration,
+and the fluctuation rate ``f`` and ``max_swaps`` from the mix.
 
 The key frequencies follow the paper's synthetic generator (arXiv:1610.05121
 Sec. V): Zipf(``z``) over ``K`` key ids, the ranks laid over the ids by a
@@ -46,18 +52,19 @@ class Mix:
     """One traffic mix, as its file states it."""
 
     name: str
-    f: float                    # fluctuation rate; 0: the frequencies stay
-    max_swaps: int              # swaps tried per interval before giving up
     warmup_intervals: int       # intervals run in set-up, before the window
     layout_seed: int            # seed of the layout and of every swap
     pool_rate: float            # tuples/s the pool is sized for
+    params: dict                # the file's other keys, for the generator
 
     @classmethod
     def load(cls, name: str) -> "Mix":
         path = TRAFFIC_DIR / f"{name}.json"
         raw = json.loads(path.read_text())
-        fields = {f.name for f in dataclasses.fields(cls)}
-        mix = cls(name=name, **{k: v for k, v in raw.items() if k in fields})
+        fields = {f.name for f in dataclasses.fields(cls)} - {"params"}
+        mix = cls(name=name,
+                  params={k: v for k, v in raw.items() if k not in fields},
+                  **{k: v for k, v in raw.items() if k in fields})
         if mix.pool_rate <= 0:
             raise ValueError(f"{path}: pool_rate must be positive")
         return mix
@@ -139,7 +146,9 @@ def draw(probs: np.ndarray, tuples: int, seed: int,
 def traffic(cfg: dict, mix: Mix, intervals: int, seed: int
             ) -> List[np.ndarray]:
     """The key arrays of ``intervals`` intervals of ``mix`` under ``cfg``."""
-    probs = frequencies(cfg["domain"], cfg["zipf"], intervals, f=mix.f,
-                        tasks=cfg["tasks"], hash_seed=cfg["hash_seed"],
-                        max_swaps=mix.max_swaps, layout_seed=mix.layout_seed)
+    probs = frequencies(cfg["domain"], cfg["zipf"], intervals,
+                        f=mix.params["f"], tasks=cfg["tasks"],
+                        hash_seed=cfg["hash_seed"],
+                        max_swaps=mix.params["max_swaps"],
+                        layout_seed=mix.layout_seed)
     return draw(probs, cfg["tuples"], seed)

@@ -8,7 +8,7 @@ import tiny
 
 @pytest.mark.parametrize("cell", tiny.CELLS)
 def test_cell_is_correct_and_its_control_is_not(cell):
-    result = tiny.run(cell, control=True)
+    result, _ = tiny.run(cell, control=True)
     checks = result["checks"]
     assert result["correct"], checks
     assert result["failed"] == 0 and result["attempted"] > 0
@@ -26,7 +26,7 @@ def test_cell_is_correct_and_its_control_is_not(cell):
 def test_traced_run_reports_host_metrics():
     """On the CPU the trace has no TPU plane: device metrics are left out,
     the host-clock and counter metrics are there."""
-    result = tiny.run("wordcount.drift.sat", trace=True)
+    result, _ = tiny.run("wordcount.drift.sat", trace=True)
     assert result["correct"]
     assert "interval_ms.sat" in result["metrics"]
     assert "plan_ms.sat" in result["metrics"]

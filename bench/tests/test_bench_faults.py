@@ -50,7 +50,7 @@ FAULTS = [state_unchanged, half_batch, answer_off_by_one]
 @pytest.mark.parametrize("fault", FAULTS, ids=lambda f: f.__name__)
 def test_fault_is_not_correct(cell, fault, monkeypatch):
     fault(monkeypatch)
-    result = tiny.run(cell)
+    result, _ = tiny.run(cell)
     assert not result["correct"]
     assert any(c["value"] > c["limit"] for c in result["checks"].values())
 

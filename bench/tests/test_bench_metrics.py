@@ -8,6 +8,7 @@ import pytest
 from jax.profiler import ProfileData
 
 import core
+import spanreduce
 import tracereduce
 
 DATA = Path(__file__).resolve().parent / "data"
@@ -64,3 +65,35 @@ def test_device_readers_are_silent_without_a_trace():
     for name in ("device_idle_pct.sat", "interval_step_add_roofline",
                  "routing_lookup_roofline"):
         assert read(name, run) is None
+
+
+SPAN_READERS = {"route_ms.sat": ("route",), "step_host_ms.sat": ("step",),
+                "pull_ms.sat": ("pull",), "finish_ms.sat": ("finish",),
+                "stats_ms.sat": ("stats", "trigger"),
+                "migrate_ms.sat": ("migrate", "pause"),
+                "untraced_ms.sat": ("untraced",)}
+
+
+@pytest.mark.parametrize("name", sorted(SPAN_READERS) + ["d2h_kb.sat",
+                                                         "h2d_kb.sat"])
+def test_span_and_counter_readers(name):
+    """Per completed interval: the self times of the reader's spans in ms,
+    a counter's change in kB; silent without a trace, or where the program
+    has no such span or counter."""
+    ivs = [core.Interval(10, due=0, handed=0, done=1)] * 4 \
+        + [core.Interval(10, due=4, handed=4)]
+    seconds = {n: 0.001 * (i + 1) for i, n in enumerate(
+        ("pause", "route", "step", "pull", "finish", "stats", "trigger",
+         "plan", "migrate", "untraced"))}
+    spans = spanreduce.SpanSummary(seconds, {})
+    counters = {"d2h_bytes": 1_310_800, "h2d_bytes": 349_600, "plans": 4}
+    run = fake_run(intervals=ivs, spans=spans, counters=counters)
+    if name in SPAN_READERS:
+        want = 1e3 * sum(seconds[n] for n in SPAN_READERS[name]) / 4
+    else:
+        want = counters[name.replace("_kb.sat", "_bytes")] / 1e3 / 4
+    assert read(name, run) == pytest.approx(want, rel=1e-12)
+    assert read(name, fake_run(intervals=ivs)) is None
+    lacking = fake_run(intervals=ivs, counters={"plans": 4}, spans=(
+        spanreduce.SpanSummary({"plan": 0.1}, {})))
+    assert read(name, lacking) is None

@@ -1,6 +1,8 @@
 """The traffic generator: the paper's fluctuation rule, open loop, and a
 function of the seed and the mix alone."""
 
+import dataclasses
+import hashlib
 import json
 
 import numpy as np
@@ -9,6 +11,7 @@ import pytest
 import core
 import reference
 import source
+import tiny
 
 CFG = json.loads((core.BENCH / "configs" / "paper-wordcount.json")
                  .read_text())
@@ -76,3 +79,34 @@ def test_tuples_follow_the_interval_frequencies():
 def test_mix_files_load():
     mix = source.Mix.load("drift.sat")
     assert mix.window_intervals(10, CFG["tuples"]) >= 1
+
+
+#: sha256 of the bytes of the first three intervals of the tiny cell's
+#: traffic, as the generator drew them when the cell was first measured:
+#: the cell measures the same work for as long as these hold
+DIGESTS = {
+    2**31 + 17:
+    "e2bc17a4fd9ab58cd09e246bd99fceb6c57ceea61534b6bbc76c787d510575b5",
+    2**33 + 5:
+    "82d59c6ae0c281871d2fc94072180117276d05c7390574419f4efd176a82c18d",
+}
+
+
+@pytest.mark.parametrize("seed", sorted(DIGESTS))
+def test_traffic_is_byte_identical_to_the_first_measured(seed):
+    cfg = dict(CFG, **tiny.TINY)
+    assert core.traffic_source(cfg) is source
+    mix = dataclasses.replace(source.Mix.load("drift.sat"), **tiny.TRAFFIC)
+    digest = hashlib.sha256()
+    for keys in source.traffic(cfg, mix, 3, seed):
+        assert keys.dtype == np.int64 and keys.size == cfg["tuples"]
+        digest.update(keys.tobytes())
+    assert digest.hexdigest() == DIGESTS[seed]
+
+
+def test_mix_keys_beyond_the_harness_reach_the_generator():
+    mix = source.Mix.load("drift.sat")
+    assert (mix.warmup_intervals, mix.layout_seed) == (6, 20161018)
+    assert mix.params["f"] == 1.0 and mix.params["max_swaps"] == 200_000
+    assert not {"warmup_intervals", "layout_seed", "pool_rate"} \
+        & set(mix.params)

@@ -7,15 +7,22 @@ programs (``XLA Modules``, one event per executed jitted program, named
 The harness's own host spans (``jax.profiler.TraceAnnotation``) sit on a
 thread line of the ``/host:CPU`` plane, on the same clock.
 
+The program marks its host phases with spans of its own
+(``repro.core.telemetry.SPANS``) on the same plane; they nest on a thread
+line.
+
 * busy: the union of the operation intervals on a chip inside the window
   span, averaged over the chips that ran anything;
 * a program's device time: the summed durations of its module events;
-* idle gaps: the stretches of the window in which no operation ran, each
-  named after the harness span that overlaps it most.
+* idle gaps: the stretches of the window in which no operation ran, cut at
+  program-span boundaries (:func:`cut_gaps`): each piece is named after the
+  innermost program span open there, else after the harness span that
+  overlaps it most, so a gap names the host phase that left the chip idle.
 """
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -87,6 +94,102 @@ class Events:
                    [n for _, _, n in ev])
 
 
+Span = Tuple[str, float, float]                 # name, start ns, end ns
+
+
+@dataclasses.dataclass
+class Spans:
+    """Program and harness spans of a trace's host plane, each list sorted
+    by start; one program span list per thread line (spans nest there)."""
+
+    program: List[List[Span]]
+    harness: List[Span]
+
+    @classmethod
+    def of(cls, profile, program_names: Sequence[str],
+           harness_names: Sequence[str]) -> "Spans":
+        plane = profile.find_plane_with_name(HOST_PLANE)
+        program, harness = [], []
+        wanted, outer = set(program_names), set(harness_names)
+        for line in (plane.lines if plane is not None else ()):
+            mine = []
+            for e in line.events:
+                t = (e.name, float(e.start_ns),
+                     float(e.start_ns + e.duration_ns))
+                if e.name in wanted:
+                    mine.append(t)
+                elif e.name in outer:
+                    harness.append(t)
+            if mine:
+                program.append(sorted(mine, key=lambda t: (t[1], -t[2])))
+        return cls(program, sorted(harness, key=lambda t: t[1]))
+
+
+def segments(spans: Sequence[Span]) -> List[Span]:
+    """The stretches of a line's nested spans (sorted by start, then by
+    end descending), each named after the innermost span open there; the
+    stretches no span covers are left out."""
+    out: List[Span] = []
+    stack: List[Tuple[str, float]] = []
+    cur = 0.0
+    for name, s, e in spans:
+        while stack and stack[-1][1] <= s:
+            top, end = stack.pop()
+            out.append((top, cur, end))
+            cur = end
+        if stack:
+            out.append((stack[-1][0], cur, s))
+        stack.append((name, e))
+        cur = s
+    while stack:
+        top, end = stack.pop()
+        out.append((top, cur, end))
+        cur = end
+    return [t for t in out if t[2] > t[1]]
+
+
+def _harness_name(harness: Sequence[Span], starts: Sequence[float],
+                  a: float, b: float) -> str:
+    """The harness span overlapping ``[a, b)`` most; ``"none"`` where none
+    does."""
+    best, overlap = "none", 0.0
+    for name, s, e in harness[max(0, bisect.bisect_right(starts, a) - 1):
+                              bisect.bisect_left(starts, b)]:
+        o = min(e, b) - max(s, a)
+        if o > overlap:
+            best, overlap = name, o
+    return best
+
+
+def cut_gaps(gaps: Sequence[Tuple[float, float]], spans: Spans
+             ) -> List[Tuple[str, float]]:
+    """Each idle gap (ns, sorted, disjoint) cut at program-span boundaries:
+    ``(name, seconds)`` per piece, in time order. A piece is named after
+    the innermost program span open there, else after the harness span
+    that overlaps it most."""
+    segs = sorted((t for line in spans.program for t in segments(line)),
+                  key=lambda t: t[1])
+    seg_starts = [s for _, s, _ in segs]
+    harness_starts = [s for _, s, _ in spans.harness]
+    out: List[Tuple[str, float]] = []
+    for gs, ge in gaps:
+        cur = gs
+        i = max(0, bisect.bisect_right(seg_starts, gs) - 1)
+        for name, s, e in segs[i:bisect.bisect_left(seg_starts, ge)]:
+            s, e = max(s, cur), min(e, ge)
+            if e <= s:
+                continue
+            if s > cur:
+                out.append((_harness_name(spans.harness, harness_starts,
+                                          cur, s), (s - cur) * 1e-9))
+            out.append((name, (e - s) * 1e-9))
+            cur = e
+        if ge > cur:
+            out.append((_harness_name(spans.harness, harness_starts,
+                                      cur, ge), (ge - cur) * 1e-9))
+    return out
+
+
 @dataclasses.dataclass
 class Summary:
     """What the per-layer readers take from a trace."""
@@ -95,7 +198,7 @@ class Summary:
     busy_s: float                          # averaged over chips that ran
     programs: Dict[str, Tuple[int, float]]  # name -> (calls, device s)
     ops: List[Tuple[str, float]]           # (program:op, device s), top first
-    idle_gaps: List[Tuple[str, float]]     # (host span, s), longest first
+    idle_gaps: List[Tuple[str, float]]     # (host span, s) pieces, longest
 
     @property
     def idle_share(self) -> float:
@@ -120,16 +223,18 @@ def host_spans(profile, names: Sequence[str]
             for line in plane.lines for e in line.events if e.name in wanted]
 
 
-def reduce(profile, span_names: Sequence[str], top: int = 10
+def reduce(profile, span_names: Sequence[str],
+           program_names: Sequence[str] = (), top: int = 10
            ) -> Optional[Summary]:
     """Reduce a ``jax.profiler.ProfileData``; None when the trace holds no
-    window span or no device plane with events in it."""
-    spans = host_spans(profile, list(span_names) + [WINDOW_SPAN])
-    window = [(s, e) for n, s, e in spans if n == WINDOW_SPAN]
+    window span or no device plane with events in it. ``span_names`` are
+    the harness's spans, ``program_names`` the program's; the ``top``
+    longest idle pieces are kept."""
+    window = host_spans(profile, [WINDOW_SPAN])
     if not window:
         return None
-    lo, hi = window[0]
-    spans = [t for t in spans if t[0] != WINDOW_SPAN]
+    _, lo, hi = window[0]
+    spans = Spans.of(profile, program_names, span_names)
     busy_total, chips = 0.0, 0
     programs: Dict[str, List[float]] = {}
     ops: Dict[str, float] = {}
@@ -162,13 +267,7 @@ def reduce(profile, span_names: Sequence[str], top: int = 10
                         if j >= 0 and mods.end[order[j]] >= s else "?")
                 key = f"{prog}:{op_name(n)}"
                 ops[key] = ops.get(key, 0.0) + (e - s) * 1e-9
-        for gs, ge in gaps(busy, lo, hi):
-            best, overlap = "none", 0.0
-            for n, s, e in spans:
-                o = min(e, ge) - max(s, gs)
-                if o > overlap:
-                    best, overlap = n, o
-            idle.append((best, (ge - gs) * 1e-9))
+        idle.extend(cut_gaps(gaps(busy, lo, hi), spans))
     if not chips:
         return None
     idle.sort(key=lambda t: -t[1])
