@@ -18,11 +18,11 @@ def check_count_stage(cfg: dict, stage: dict, ref: KeyedCounts,
                       prefix: str) -> Dict[str, float]:
     out = check_outputs(stage, ref, prefix)
     out.update(check_controller(
-        stage, (c.astype(np.float64) for c in ref.counts),
-        domain=cfg["domain"], theta_max=cfg["theta_max"],
-        table_max=cfg["table_max"], prefix=prefix))
+        stage, ((ids, c.astype(np.float64)) for ids, c in ref.counts),
+        theta_max=cfg["theta_max"], table_max=cfg["table_max"],
+        prefix=prefix))
     held, slots = ref.held()
-    out.update(check_owners(stage, held, slots, domain=cfg["domain"],
+    out.update(check_owners(stage, held, slots,
                             slot_bytes=cfg["state_bytes_per_slot"],
                             prefix=prefix))
     return out
@@ -38,7 +38,7 @@ def counted_answers(ref: KeyedCounts) -> dict:
 def check(cfg: dict, intervals: Sequence[np.ndarray],
           observed: dict) -> Dict[str, float]:
     """Numbers that are 0 on a sound run."""
-    ref = KeyedCounts(cfg["domain"], cfg["window"]).run(intervals)
+    ref = KeyedCounts(cfg["window"]).run(intervals)
     return check_count_stage(cfg, observed["count"], ref, "")
 
 
@@ -46,6 +46,5 @@ def control(cfg: dict, intervals: Sequence[np.ndarray],
             observed: dict) -> dict:
     """The reference in the program's place, its window kept in
     ``CONTROL_DTYPE``; routing and plans are the program's own."""
-    ref = KeyedCounts(cfg["domain"], cfg["window"],
-                      CONTROL_DTYPE).run(intervals)
+    ref = KeyedCounts(cfg["window"], CONTROL_DTYPE).run(intervals)
     return {"count": dict(observed["count"], **counted_answers(ref))}

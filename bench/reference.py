@@ -24,6 +24,10 @@ The routing tables are what the controller answered, so they are checked
 against these rules rather than taken as given: loads and ownership are
 recomputed from them with this module's own hash.
 
+Everything is worked out over the key ids an interval holds, never over a
+dense ``[0, domain)``: an interval's counts are ``(ids, counts)``, and
+memory follows the keys present, not the largest id.
+
 The ``check_*`` functions return numbers that are 0 on a sound run: counts
 of keys, cells or intervals that disagree, and absolute gaps.
 """
@@ -47,20 +51,61 @@ def fmix32(keys: np.ndarray, seed: int) -> np.ndarray:
     return h
 
 
+Table = Tuple[np.ndarray, np.ndarray]
+NO_KEYS = np.zeros(0, np.int64)
+NO_TABLE: Table = (NO_KEYS, NO_KEYS)
+
+
+def find(ids: np.ndarray, keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """Where each of ``keys`` sits in the sorted, distinct ``ids``, and
+    whether it is there."""
+    pos = np.searchsorted(ids, keys)
+    there = pos < ids.size
+    there[there] = ids[pos[there]] == keys[there]
+    return pos, there
+
+
+def values_at(ids: np.ndarray, vals: np.ndarray, keys: np.ndarray
+              ) -> np.ndarray:
+    """``vals`` of the sorted, distinct ``ids`` at each of ``keys``, 0
+    where ``ids`` lacks it."""
+    out = np.zeros(keys.size, vals.dtype)
+    pos, there = find(ids, keys)
+    out[there] = vals[pos[there]]
+    return out
+
+
+def route(ids: np.ndarray, tasks: int, seed: int, table: Table
+          ) -> np.ndarray:
+    """``F(k)`` of each of the sorted, distinct key ids ``ids``: the
+    table's destination where it holds k, else ``fmix32(k ^ seed) %
+    tasks``."""
+    dest = (fmix32(ids, seed) % np.uint32(tasks)).astype(np.int64)
+    tkeys, tdests = table
+    pos, there = find(ids, tkeys)
+    dest[pos[there]] = tdests[there]
+    return dest
+
+
 def hashed(domain: int, tasks: int, seed: int) -> np.ndarray:
     """``fmix32(k ^ seed) % tasks`` for every key of ``[0, domain)``."""
-    return (fmix32(np.arange(domain, dtype=np.int64), seed)
-            % np.uint32(tasks)).astype(np.int64)
+    return route(np.arange(domain, dtype=np.int64), tasks, seed, NO_TABLE)
 
 
-def route(base: np.ndarray, table: Tuple[np.ndarray, np.ndarray]
-          ) -> np.ndarray:
-    """Dense ``F(k)``: the hashed destinations ``base``, then the table's
-    overrides."""
-    dest = base.copy()
-    tkeys, tdests = table
-    dest[tkeys] = tdests
-    return dest
+def present(keys: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """The distinct ids of ``keys``, ascending, and how often each occurs
+    (int32): a count over the band of ids present where it is narrow,
+    else a sort."""
+    keys = np.asarray(keys, np.int64)
+    if keys.size == 0:
+        return NO_KEYS, np.zeros(0, np.int32)
+    low = int(keys.min())
+    if int(keys.max()) - low < 4 * keys.size:
+        counts = np.bincount(keys - low)
+        ids = np.flatnonzero(counts)
+        return ids + low, counts[ids].astype(np.int32)
+    ids, counts = np.unique(keys, return_counts=True)
+    return ids, counts.astype(np.int32)
 
 
 def imbalance(loads: np.ndarray) -> float:
@@ -70,67 +115,70 @@ def imbalance(loads: np.ndarray) -> float:
     return max(0.0, float(np.max(loads - mean) / mean))
 
 
-def window_walk(counts: Sequence[np.ndarray], window: int,
-                dtype=np.int64) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
-    """``(c_i, W0_i)`` for each interval: its per-key counts and the count
-    over the ``window`` intervals before it. ``dtype`` is the arithmetic the
-    window is kept in."""
-    ring: List[np.ndarray] = []
-    for c in counts:
+def window_walk(counts: Sequence[Tuple[np.ndarray, np.ndarray]],
+                window: int, dtype=np.int64
+                ) -> Iterator[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
+    """``(ids_i, c_i, W0_i)`` for each interval: the key ids it holds, their
+    counts, and each one's count over the ``window`` intervals before it.
+    ``dtype`` is the arithmetic the window is kept in."""
+    ring: List[Tuple[np.ndarray, np.ndarray]] = []
+    for ids, c in counts:
         c = c.astype(dtype)
         w0 = np.zeros_like(c)
-        for prev in ring:
-            w0 = w0 + prev
-        yield c, w0
-        ring = (ring + [c])[-window:]
+        for prev_ids, prev in ring:
+            w0 = w0 + values_at(prev_ids, prev, ids)
+        yield ids, c, w0
+        ring = (ring + [(ids, c)])[-window:]
+
+
+def last_of(keys: Sequence[np.ndarray], vals: Sequence[np.ndarray]
+            ) -> Tuple[np.ndarray, np.ndarray]:
+    """The distinct keys of the concatenated ``keys``, ascending, each with
+    the value of its last entry."""
+    k, v = np.concatenate(keys)[::-1], np.concatenate(vals)[::-1]
+    out_key, first = np.unique(k, return_index=True)
+    return out_key, v[first]
 
 
 class KeyedCounts:
     """What a windowed count stage must answer for a stream of intervals;
     ``dtype`` is the arithmetic its window is kept in."""
 
-    def __init__(self, domain: int, window: int, dtype=np.int64):
-        self.domain = domain
+    def __init__(self, window: int, dtype=np.int64):
         self.window = window
         self.dtype = dtype
-        self.counts: List[np.ndarray] = []
+        self.counts: List[Tuple[np.ndarray, np.ndarray]] = []
         self.out_key = np.zeros(0, np.int64)
         self.out_val = np.zeros(0, np.int64)
         self.emitted = 0
 
-    def run(self, intervals: Sequence[np.ndarray]) -> "KeyedCounts":
-        self.counts = [np.bincount(k, minlength=self.domain)
-                       .astype(np.int32) for k in intervals]
-        ever = np.zeros(self.domain, dtype=bool)
-        out = np.zeros(self.domain, dtype=np.int64)
-        for c, w0 in window_walk(self.counts, self.window, self.dtype):
+    def run(self, intervals: Iterable[np.ndarray]) -> "KeyedCounts":
+        self.counts = [present(k) for k in intervals]
+        keys, vals = [NO_KEYS], [NO_KEYS]
+        for ids, c, w0 in window_walk(self.counts, self.window, self.dtype):
             seen = c > 0
             # the j-th tuple of a key emits w0 + j; a key's last emit is
             # its window total, and its emits sum to c*w0 + c(c+1)/2
             cs, ws = c[seen].astype(np.int64), w0[seen].astype(np.int64)
             self.emitted += int(np.dot(cs, ws)) + int(np.dot(cs, cs + 1) // 2)
-            out[seen] = (w0 + c)[seen].astype(np.int64)
-            ever |= seen
-        self.out_key = np.nonzero(ever)[0]
-        self.out_val = out[self.out_key]
+            keys.append(ids[seen])
+            vals.append((w0 + c)[seen].astype(np.int64))
+        self.out_key, self.out_val = last_of(keys, vals)
         return self
 
     def held(self) -> Tuple[np.ndarray, np.ndarray]:
         """Keys with state after the last interval, and how many of the
         last ``window`` intervals each appeared in."""
-        slots = np.zeros(self.domain, dtype=np.int64)
-        for c in self.counts[-self.window:]:
-            slots += c > 0
-        keys = np.nonzero(slots)[0]
-        return keys, slots[keys]
+        recent = [ids for ids, _ in self.counts[-self.window:]]
+        keys, slots = np.unique(np.concatenate([NO_KEYS] + recent),
+                                return_counts=True)
+        return keys, slots.astype(np.int64)
 
 
-def tables_in_force(n_intervals: int, plans: Sequence[dict]
-                    ) -> List[Tuple[np.ndarray, np.ndarray]]:
+def tables_in_force(n_intervals: int, plans: Sequence[dict]) -> List[Table]:
     """Routing table of each interval 1..n: the last plan made at the end
     of an earlier interval, else empty."""
-    empty = (np.zeros(0, np.int64), np.zeros(0, np.int64))
-    out, cur, by_iv = [], empty, {p["interval"]: p for p in plans}
+    out, cur, by_iv = [], NO_TABLE, {p["interval"]: p for p in plans}
     for i in range(1, n_intervals + 1):
         out.append(cur)
         if i in by_iv:
@@ -138,23 +186,22 @@ def tables_in_force(n_intervals: int, plans: Sequence[dict]
     return out
 
 
-def check_controller(stage: dict, costs: Iterable[np.ndarray], *,
-                     domain: int, theta_max: float, table_max: int,
+def check_controller(stage: dict,
+                     costs: Iterable[Tuple[np.ndarray, np.ndarray]], *,
+                     theta_max: float, table_max: int,
                      prefix: str) -> Dict[str, float]:
     """Loads, triggers, tables and plans of one stage against its costs
-    per key and interval (one array per interval the stage reported)."""
+    per key and interval: one ``(ids, costs)`` pair per interval the stage
+    reported, ``ids`` sorted and distinct, a key absent costing nothing."""
     n = len(stage["loads"])
     tasks, seed = stage["tasks"], stage["hash_seed"]
     plans = stage["plans"]
     tables = tables_in_force(n, plans)
     planned = {p["interval"] for p in plans}
     loads_wrong = triggers_wrong = tables_wrong = plans_over = 0
-    base = hashed(domain, tasks, seed)
-    dest, dest_of = None, None
-    for i, (cost, table) in enumerate(zip(costs, tables), start=1):
-        if dest_of is not table:
-            dest, dest_of = route(base, table), table
-        loads = np.bincount(dest, weights=cost, minlength=tasks)
+    for i, ((ids, cost), table) in enumerate(zip(costs, tables), start=1):
+        loads = np.bincount(route(ids, tasks, seed, table), weights=cost,
+                            minlength=tasks)
         loads_wrong += int(np.sum(loads != stage["loads"][i - 1]))
         triggers_wrong += int((imbalance(loads) > theta_max)
                               != (i in planned))
@@ -163,7 +210,7 @@ def check_controller(stage: dict, costs: Iterable[np.ndarray], *,
                             or size > table_max)
         if i in planned:
             p = [q for q in plans if q["interval"] == i][0]
-            after = route(base, (p["keys"], p["dests"]))
+            after = route(ids, tasks, seed, (p["keys"], p["dests"]))
             plans_over += int(
                 imbalance(np.bincount(after, weights=cost, minlength=tasks))
                 > theta_max or p["keys"].size > table_max)
@@ -174,25 +221,40 @@ def check_controller(stage: dict, costs: Iterable[np.ndarray], *,
 
 
 def check_owners(stage: dict, held_keys: np.ndarray, slots: np.ndarray, *,
-                 domain: int, slot_bytes: float,
-                 prefix: str) -> Dict[str, float]:
+                 slot_bytes: float, prefix: str) -> Dict[str, float]:
     """Each key with state lives on the task the final table routes it to,
     no other key has state, and its state holds one slot per interval it
-    appeared in."""
+    appeared in. ``held_keys`` are sorted and distinct; owners and sizes
+    are compared over the keys held or owned."""
     final = ((stage["plans"][-1]["keys"], stage["plans"][-1]["dests"])
-             if stage["plans"] else (np.zeros(0, np.int64),) * 2)
-    dest = route(hashed(domain, stage["tasks"], stage["hash_seed"]), final)
-    want_owner = np.full(domain, -1, dtype=np.int64)
-    want_owner[held_keys] = dest[held_keys]
-    want_size = np.zeros(domain)
-    want_size[held_keys] = slot_bytes * slots
-    got_owner = np.full(domain, -1, dtype=np.int64)
-    got_size = np.zeros(domain)
-    twice = 0
-    for task, (keys, sizes) in enumerate(stage["owned"]):
-        twice += int(np.sum(got_owner[keys] >= 0))
-        got_owner[keys] = task
-        got_size[keys] = sizes
+             if stage["plans"] else NO_TABLE)
+    owned = stage["owned"]
+    keys = np.concatenate([NO_KEYS] + [k for k, _ in owned])
+    sizes = np.concatenate([np.zeros(0)] + [np.asarray(s, np.float64)
+                                            for _, s in owned])
+    task = np.repeat(np.arange(len(owned)), [k.size for k, _ in owned])
+    # group each key's entries, in the order the tasks listed them
+    order = np.argsort(keys, kind="stable")
+    keys, sizes, task = keys[order], sizes[order], task[order]
+    first = np.r_[True, keys[1:] != keys[:-1]][:keys.size]
+    last = np.r_[first[1:], True][:keys.size]
+    # an entry of a task after the first task that lists its key; the last
+    # entry of a key is the one that stands
+    twice = int(np.sum(task > task[first][np.cumsum(first) - 1]))
+    owned_keys = keys[last]
+
+    union = np.union1d(held_keys, owned_keys)
+    want_owner = np.full(union.size, -1, dtype=np.int64)
+    want_size = np.zeros(union.size)
+    at = np.searchsorted(union, held_keys)
+    want_owner[at] = route(held_keys, stage["tasks"], stage["hash_seed"],
+                           final)
+    want_size[at] = slot_bytes * slots
+    got_owner = np.full(union.size, -1, dtype=np.int64)
+    got_size = np.zeros(union.size)
+    at = np.searchsorted(union, owned_keys)
+    got_owner[at] = task[last]
+    got_size[at] = sizes[last]
     return {f"{prefix}owners_wrong": int(np.sum(got_owner != want_owner))
             + twice,
             f"{prefix}sizes_wrong": int(np.sum(got_size != want_size))}

@@ -32,3 +32,13 @@ def test_traced_run_reports_host_metrics():
     assert "plan_ms.sat" in result["metrics"]
     assert "device_idle_pct.sat" not in result["metrics"]
 
+
+def test_without_a_sizes_file_a_cell_runs_at_the_default_sizes(
+        tmp_path, monkeypatch):
+    assert not (tiny.SIZES / "paper-wordcount.json").exists()
+    monkeypatch.setattr(tiny, "SIZES", tmp_path)
+    assert tiny.sizes("wordcount.drift.sat") == (tiny.TINY, tiny.TRAFFIC)
+    result, run = tiny.run("wordcount.drift.sat")
+    assert result["correct"], result["checks"]
+    assert run.mix.warmup_intervals == tiny.TRAFFIC["warmup_intervals"]
+    assert result["attempted"] == tiny.TINY["tuples"] * len(run.intervals)

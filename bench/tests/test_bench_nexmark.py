@@ -1,6 +1,8 @@
 """NEXmark's bid generator (``sources/nexmark.py``) against the rules of
 Beam's ``AuctionGenerator``/``BidGenerator``, and a configuration that
-names it driving a cell end to end."""
+names it driving a cell end to end, by overrides or by a sizes file."""
+
+import json
 
 import numpy as np
 import pytest
@@ -103,4 +105,19 @@ def test_a_configuration_naming_nexmark_runs_a_cell():
     result, run = tiny.run("wordcount.drift.sat", overrides=overrides,
                            traffic_overrides={"pool_rate": 46_000})
     assert result["correct"], result["checks"]
+    assert result["attempted"] == 46_000 * len(run.intervals)
+
+
+def test_a_sizes_file_switches_a_cell_to_nexmark(tmp_path, monkeypatch):
+    """A sizes file for the cell's configuration, found by its name, sets
+    the tiny run: here NEXmark's bids at Beam's 10,000 events a second in
+    place of the default sizes."""
+    sizes = {"overrides": dict(BEAM, traffic_source="nexmark",
+                               domain=2**15),
+             "traffic": {"warmup_intervals": 4, "pool_rate": 46_000}}
+    (tmp_path / "paper-wordcount.json").write_text(json.dumps(sizes))
+    monkeypatch.setattr(tiny, "SIZES", tmp_path)
+    result, run = tiny.run("wordcount.drift.sat")
+    assert result["correct"], result["checks"]
+    assert run.mix.warmup_intervals == 4
     assert result["attempted"] == 46_000 * len(run.intervals)
