@@ -33,7 +33,9 @@ migrate    the relabel of the keys a plan moved
 ``plans``, ``migrated_keys`` (``bench/spanreduce.py`` prints each per
 interval), ``d2h_copies`` (one per device-to-host copy, whether or not it
 was started ahead; ``bench/spanreduce.py``'s ``counters`` hold its change
-over the window), and ``retrace.<module>.<function>`` (incremented while a
+over the window), ``paused_tuples`` (the tuples a device backend's pause
+window buffers, its reports' ``buffered`` summed), and
+``retrace.<module>.<function>`` (incremented while a
 jitted function is traced, so a test can see that it compiled once).
 """
 

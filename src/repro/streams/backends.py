@@ -708,10 +708,7 @@ class DeviceBackend(StateBackend):
 
         buffered_count = 0
         pause_hi = stage.pause_window(n)
-        if pause_hi is not None:
-            with span("pause"):
-                buffered_count = int(np.isin(keys[:pause_hi],
-                                             stage._pending_delta_arr).sum())
+        moved = stage._pending_delta_arr
         stage.clear_pause()
 
         # ring-column bookkeeping (host mirror of the columnar _col_iv)
@@ -746,6 +743,15 @@ class DeviceBackend(StateBackend):
                     "allocates state per key id — raise device_domain_max or "
                     "use the columnar backend for sparse huge domains")
             fleet.ensure_domain(kmax + 1)
+            if pause_hi is not None:
+                # the domain is dense and now covers every key, so a mask of
+                # the moved ids gathered by the paused prefix counts it
+                with span("pause"):
+                    mask = np.zeros(fleet.domain, dtype=bool)
+                    mask[moved[moved < fleet.domain]] = True
+                    buffered_count = int(np.count_nonzero(
+                        mask.take(keys[:pause_hi])))
+                count("paused_tuples", buffered_count)
             dest_dev = self._dest_dense()
             cur = np.zeros(w1, dtype=np.int32)
             cur[c] = 1

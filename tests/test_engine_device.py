@@ -468,6 +468,65 @@ def test_device_rejects_out_of_domain_keys():
     assert stage.total_state_keys() == 2
 
 
+@pytest.mark.parametrize("case", ["domain_grows", "moved_absent", "rescale",
+                                  "raise_clears"])
+def test_buffered_count_equals_isin(case):
+    """The device backend counts the pause window's buffered tuples by a
+    gather from a dense mask of the moved keys: that count reads as
+    ``np.isin`` over the same prefix and as the object backend's report,
+    when the interval grows the domain, when no moved key is in the prefix,
+    after a rescale's large moved set, and after an interval that raised."""
+    gen = WorkloadGen(k=400, z=1.1, f=0.8, seed=5, window=2)
+    dev, obj = stages = [make_stage(WordCount(), b, window=2, theta_max=0.0)
+                         for b in ("device", "object")]
+
+    def run(keys, backends=stages):
+        pause_hi = dev.pause_window(keys.size)
+        moved = dev._pending_delta_arr
+        want = 0 if pause_hi is None else int(
+            np.isin(keys[:pause_hi], moved).sum())
+        for stage in backends:
+            stage.process_interval_arrays(keys, np.zeros(keys.size))
+            assert stage.reports[-1].buffered == want, stage.state_backend
+        return want
+
+    def draw():
+        gen.interval(dev.controller.assignment)
+        return gen.draw_tuples(1000).astype(np.int64)
+
+    for _ in range(3):
+        run(draw())
+    if case == "rescale":
+        for stage in stages:
+            stage.scale_to(7)
+    moved = dev._pending_delta_arr
+    assert moved is not None and moved.size
+    keys = draw()
+    pause_hi = dev.pause_window(keys.size)
+    fleet = dev.backend._fleet
+    domain = fleet.domain
+
+    if case == "domain_grows":
+        keys[0] = 4 * domain + 3
+        keys[1] = moved[0]
+        assert run(keys) > 0
+        assert fleet.domain > domain
+    elif case == "moved_absent":
+        prefix = keys[:pause_hi]
+        prefix[np.isin(prefix, moved)] = np.setdiff1d(keys, moved)[0]
+        assert run(keys) == 0
+    elif case == "rescale":
+        assert moved.size > 20
+        assert np.setdiff1d(moved, keys).size
+        run(keys)
+    else:
+        keys[pause_hi - 1] = -1
+        with pytest.raises(ValueError, match="non-negative"):
+            dev.process_interval_arrays(keys, np.zeros(keys.size))
+        assert dev._pending_delta_arr is None
+        assert run(draw(), backends=[dev]) == 0
+
+
 def test_device_max_mode_rejects_out_of_int32_values():
     stage = make_stage(MergeCounts(), "device")
     with pytest.raises(ValueError, match="int32"):

@@ -19,7 +19,7 @@ ENTRY = "entry"          # the test's own span around each entry call
 N_TASKS, WINDOW, TABLE_MAX, TUPLES = 6, 2, 100, 20_000
 WARM, TRACED = 2, 5
 WATCHED = ("d2h_bytes", "h2d_bytes", "route_refreshes", "plans",
-           "migrated_keys")
+           "migrated_keys", "paused_tuples")
 
 
 def _stage(backend: str) -> KeyedStage:
@@ -136,6 +136,12 @@ def test_upload_bytes_match_the_shapes(traced):
 def test_migrated_keys_count_the_plans_moves(traced):
     _, _, delta, plans = traced
     assert delta["migrated_keys"] == sum(len(p.moved_keys) for p in plans)
+
+
+def test_paused_tuples_count_the_buffered_tuples(traced):
+    stage, _, delta, _ = traced
+    buffered = sum(r.buffered for r in stage.reports[WARM:])
+    assert delta["paused_tuples"] == buffered > 0
 
 
 def test_plan_span_times_the_plan(traced):
